@@ -39,6 +39,7 @@ from repro.configs import (ARCH_REGISTRY, WEIGHT_FORMATS,
                            apply_decode_kernel_override,
                            apply_spec_decode_overrides,
                            apply_weight_format_override, get_config)
+from repro.launch.serve import device_label
 from repro.models import model_zoo
 from repro.serving import kv_cache as kvc
 from repro.serving import sharded as shd
@@ -217,7 +218,7 @@ def main():
     print(f"[serve] arch={cfg.name} kv={args.kv_format} "
           f"admission={args.admission}: decoded {stats['decoded_tokens']} "
           f"tokens across {args.batch} seqs in {dt*1e3:.1f} ms "
-          f"({stats['tokens_per_s']:.1f} tok/s on CPU smoke, "
+          f"({stats['tokens_per_s']:.1f} tok/s on {device_label()}, "
           f"occupancy {stats['mean_occupancy']:.2f})")
     print(f"[serve] ttft_s p50={stats['ttft_s']['p50']} "
           f"p95={stats['ttft_s']['p95']}  itl_s p50={stats['itl_s']['p50']} "

@@ -297,10 +297,9 @@ def roofline_from_compiled(
     cost_analysis on loop-free decode graphs (validated in tests).  XLA's
     numbers are retained in the report for reference.
     """
-    from repro import compat
     from repro.analysis.hlo import HloModule
 
-    cost = compat.cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     xla_flops = float(cost.get("flops", 0.0))
     xla_bytes = float(cost.get("bytes accessed", 0.0))
     mod = HloModule(compiled.as_text())

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 
 Tree = Any
 
@@ -73,7 +72,7 @@ def make_compressed_dp_grad_fn(
         loss = jax.lax.pmean(loss, data_axis)
         return loss, grads
 
-    return compat.shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), P(data_axis)),

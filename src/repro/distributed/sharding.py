@@ -25,7 +25,7 @@ from typing import Optional, Tuple
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
+from jax._src import mesh as _mesh_lib
 
 # logical axis vocabulary used by the model zoo
 BATCH = "batch"
@@ -217,6 +217,21 @@ def rules_for_mesh(mesh: Mesh, **kw) -> ShardingRules:
     return ShardingRules(batch_axes=batch or ("data",), mesh=mesh, **kw)
 
 
+def ambient_mesh():
+    """The ambient mesh, or None outside every mesh context.
+
+    ``jax.sharding.use_mesh`` sets an abstract mesh (bare PartitionSpec
+    constraints resolve against it); the plain ``with mesh:`` form the
+    launchers use sets only the concrete mesh in JAX's thread resources.
+    An axis-less abstract mesh counts as "none".
+    """
+    am = jax.sharding.get_abstract_mesh()
+    if am.axis_names:
+        return am
+    pm = _mesh_lib.thread_resources.env.physical_mesh
+    return None if pm.empty else pm
+
+
 def constrain(x: jax.Array, rules: ShardingRules, logical_axes) -> jax.Array:
     """with_sharding_constraint via logical axes (no-op outside jit/mesh).
 
@@ -227,7 +242,7 @@ def constrain(x: jax.Array, rules: ShardingRules, logical_axes) -> jax.Array:
     resolvable inside jit); falls back to the ambient abstract mesh.
     """
     try:
-        mesh = rules.mesh if rules.mesh is not None else compat.get_abstract_mesh()
+        mesh = rules.mesh if rules.mesh is not None else ambient_mesh()
         if mesh is None or not mesh.axis_names:
             return x
         spec = rules.spec_for_shape(mesh, tuple(logical_axes), x.shape)

@@ -18,41 +18,34 @@ from repro.kernels.bgpp_paged_attend.ref import NBITS, bgpp_paged_attend_ref
 
 
 @functools.partial(
-    jax.jit, static_argnames=("rounds", "k_max", "survivors", "interpret")
+    jax.jit,
+    static_argnames=("page_size", "rounds", "k_max", "survivors", "interpret"),
 )
 def _bgpp_pallas_path(
-    q, k_planes, k_sign, k_scale, v, v_scale, phys, pos, *,
-    rounds: int, k_max: int, survivors: Tuple[int, ...],
+    q, k_planes, k_sign, k_scale, v, v_scale, page_ids, pos, *,
+    page_size: int, rounds: int, k_max: int, survivors: Tuple[int, ...],
     interpret: bool = False,
 ):
     return bgpp_paged_attend_pallas(
-        q, k_planes, k_sign, k_scale, v, v_scale, phys, pos,
-        rounds=rounds, k_max=k_max, survivors=survivors,
+        q, k_planes, k_sign, k_scale, v, v_scale, page_ids, pos,
+        page_size=page_size, rounds=rounds, k_max=k_max, survivors=survivors,
         interpret=interpret,
     )
 
 
 @functools.partial(
-    jax.jit, static_argnames=("rounds", "k_max", "survivors")
+    jax.jit, static_argnames=("page_size", "rounds", "k_max", "survivors")
 )
-def _bgpp_ref_jit(q, k_planes, k_sign, k_scale, v, v_scale, phys, pos, *,
-                  rounds, k_max, survivors):
+def _bgpp_ref_path(q, k_planes, k_sign, k_scale, v, v_scale, page_ids, pos,
+                   *, page_size, rounds, k_max, survivors):
     return bgpp_paged_attend_ref(
-        q, k_planes, k_sign, k_scale, v, v_scale, phys, pos,
-        rounds=rounds, k_max=k_max, survivors=survivors,
+        q, k_planes, k_sign, k_scale, v, v_scale, page_ids, pos,
+        page_size=page_size, rounds=rounds, k_max=k_max, survivors=survivors,
     )
 
 
-def _bgpp_ref_path(q, k_planes, k_sign, k_scale, v, v_scale, phys, pos, *,
-                   rounds, k_max, survivors):
-    return _bgpp_ref_jit(
-        q, k_planes, k_sign, k_scale, v, v_scale, phys, pos,
-        rounds=rounds, k_max=k_max, survivors=survivors,
-    )
-
-
-def _validate(q, k_planes, k_sign, k_scale, v, v_scale, phys, pos,
-              rounds, k_max, survivors):
+def _validate(q, k_planes, k_sign, k_scale, v, v_scale, page_ids, pos,
+              page_size, rounds, k_max, survivors):
     if q.ndim != 4:
         raise ValueError(
             f"bgpp_paged_attend: q must be grouped (B, Hk, g, D), got shape "
@@ -96,23 +89,31 @@ def _validate(q, k_planes, k_sign, k_scale, v, v_scale, phys, pos,
         raise ValueError(
             f"bgpp_paged_attend: v must be (n_tok, Hk, D) int8; got {v.shape}"
         )
-    if phys.ndim != 2 or phys.shape[0] != B or pos.shape != (B,):
+    if page_ids.ndim != 2 or page_ids.shape[0] != B or pos.shape != (B,):
         raise ValueError(
-            f"bgpp_paged_attend: phys must be (B={B}, S) and pos (B,); got "
-            f"{phys.shape} / {pos.shape}"
+            f"bgpp_paged_attend: page_ids must be (B={B}, pages_per_slot) "
+            f"and pos (B,); got {page_ids.shape} / {pos.shape}"
         )
-    S = phys.shape[1]
+    if page_size < 1 or n_tok % page_size:
+        raise ValueError(
+            f"bgpp_paged_attend: pool of {n_tok} token rows is not a whole "
+            f"number of pages of page_size={page_size}"
+        )
     survivors = tuple(int(s) for s in survivors)
+    S = survivors[0] if survivors else 0
+    if -(-S // page_size) != page_ids.shape[1]:
+        raise ValueError(
+            f"bgpp_paged_attend: survivors[0]={S} must be the logical "
+            f"context the page table covers (round 0 scans every position): "
+            f"{page_ids.shape[1]} pages of {page_size} lanes hold "
+            f"{(page_ids.shape[1] - 1) * page_size + 1}.."
+            f"{page_ids.shape[1] * page_size} positions"
+        )
     if len(survivors) != rounds:
         raise ValueError(
             f"bgpp_paged_attend: plan has rounds={rounds} but "
             f"{len(survivors)} survivor widths {survivors} — pass the tuple "
             f"from kv_cache.bgpp_decode_plan unmodified"
-        )
-    if survivors[0] != S:
-        raise ValueError(
-            f"bgpp_paged_attend: survivors[0]={survivors[0]} must equal the "
-            f"logical context S={S} (round 0 scans every position)"
         )
     if any(survivors[i] < survivors[i + 1] for i in range(rounds - 1)):
         raise ValueError(
@@ -133,9 +134,10 @@ def bgpp_paged_attend(
     k_scale: jax.Array,  # (n_tok, Hk) f32
     v: jax.Array,  # (n_tok, Hk, D) int8
     v_scale: jax.Array,  # (n_tok, Hk) f32
-    phys: jax.Array,  # (B, S) int32 logical -> pool row map
+    page_ids: jax.Array,  # (B, pages_per_slot) int32, -1 = unmapped
     pos: jax.Array,  # (B,) int32 last valid logical position per slot
     *,
+    page_size: int,
     rounds: int,
     k_max: int,
     survivors: Tuple[int, ...],
@@ -145,17 +147,19 @@ def bgpp_paged_attend(
     """Fused two-phase BGPP paged decode -> f32 ``(B, Hk, g, D)``.
 
     ``(rounds, k_max, survivors)`` is the static progressive plan from
-    :func:`repro.serving.kv_cache.bgpp_decode_plan`.  Routing between
+    :func:`repro.serving.kv_cache.bgpp_decode_plan`; ``survivors[0]`` is
+    the logical context ``S`` (round 0 scores every position below it).  Routing between
     compiled / interpret / ref is governed by :mod:`repro.kernels.dispatch`.
     """
     survivors = tuple(int(s) for s in survivors)
-    _validate(q, k_planes, k_sign, k_scale, v, v_scale, phys, pos,
-              rounds, k_max, survivors)
+    _validate(q, k_planes, k_sign, k_scale, v, v_scale, page_ids, pos,
+              page_size, rounds, k_max, survivors)
     return dispatch.pallas_dispatch(
         "bgpp_paged_attend",
         _bgpp_pallas_path,
         _bgpp_ref_path,
-        q, k_planes, k_sign, k_scale, v, v_scale, phys, pos,
+        q, k_planes, k_sign, k_scale, v, v_scale, page_ids, pos,
+        page_size=page_size,
         rounds=rounds,
         k_max=k_max,
         survivors=survivors,

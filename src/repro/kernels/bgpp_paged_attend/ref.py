@@ -8,8 +8,10 @@ paged gathers (``paged_plane`` / ``paged_sign`` / ``paged_plane_rows`` /
 ``paged_topk_entry``) inlined as plain ``take``/``vmap`` so the family has
 no import edge into ``repro.serving``.  Every float op keeps the engine's
 order, so the selected candidate sets AND the final logits are
-bit-identical to the engine's jnp path (phase-1 scores are integer-exact
-plane sums; selection is order-invariant by construction).
+bit-identical to the engine's jnp path.  Phase-1 scores are integer-exact
+plane sums, and every round's survivors are kept in ascending logical
+order, so each ``top_k`` breaks ties toward the lowest logical index: the
+selection is a function of the scores alone.
 """
 
 from __future__ import annotations
@@ -20,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import bgpp as bgpp_mod, bitslice
+from repro.kernels.paged_flash_decode.ref import gather_phys
 
 NEG_INF = -1e30  # matches repro.core.attention.NEG_INF
 NBITS = bitslice.WEIGHT_MAG_BITS  # 7 magnitude planes + sign
@@ -71,7 +74,7 @@ def _topk_indices(qf, plane0, sign_full, plane_at, valid,
     cur_idx = None
     for r in range(1, rounds):
         k_r = survivors[r]
-        _, li = jax.lax.top_k(score_h, k_r)
+        li = jnp.sort(jax.lax.top_k(score_h, k_r)[1], axis=-1)
         cur_idx = li if cur_idx is None else jnp.take_along_axis(cur_idx, li, axis=2)
         partial = jnp.take_along_axis(partial, li[:, :, None, :], axis=3)
         p_r = NBITS - 1 - r
@@ -90,7 +93,7 @@ def _topk_indices(qf, plane0, sign_full, plane_at, valid,
             score_h, NEG_INF,
         )
 
-    _, li = jax.lax.top_k(score_h, k_max)
+    li = jnp.sort(jax.lax.top_k(score_h, k_max)[1], axis=-1)
     idx = li if cur_idx is None else jnp.take_along_axis(cur_idx, li, axis=2)
     idx_valid = jnp.take_along_axis(
         jnp.broadcast_to(valid[:, None, :], (B, Hk, S)), idx, axis=2
@@ -133,9 +136,10 @@ def bgpp_paged_attend_ref(
     k_scale: jax.Array,  # (n_tok, Hk) f32
     v: jax.Array,  # (n_tok, Hk, D) int8
     v_scale: jax.Array,  # (n_tok, Hk) f32
-    phys: jax.Array,  # (B, S) int32 logical->pool row gather map
+    page_ids: jax.Array,  # (B, pages_per_slot) int32, -1 = unmapped
     pos: jax.Array,  # (B,) int32 — keys at logical s <= pos[b] are valid
     *,
+    page_size: int,
     rounds: int,
     k_max: int,
     survivors: Tuple[int, ...],
@@ -147,7 +151,8 @@ def bgpp_paged_attend_ref(
     reads exactly the bytes the kv-read counter prices.
     """
     B, Hk, g, D = q.shape
-    S = phys.shape[1]
+    S = survivors[0]  # the logical context; the pages may span more lanes
+    phys = gather_phys(page_ids, page_size, S)  # (B, S) pool rows
     valid = jnp.arange(S)[None, :] <= pos[:, None]  # (B, S)
     qf = _quant_query(q)
 

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 
 def _unpack_bits_i32(packed: jax.Array) -> jax.Array:
     x = packed.astype(jnp.int32)
@@ -70,7 +68,7 @@ def bgpp_score_pallas(
         ],
         out_specs=pl.BlockSpec((tile_s, 1), lambda s: (s, 0)),
         out_shape=jax.ShapeDtypeStruct((S, 1), jnp.int32),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

@@ -28,8 +28,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 
 def _kernel(
     idx_ref,  # (1, TG, TK) uint8 group patterns for plane p
@@ -125,7 +123,7 @@ def brcr_gemm_pallas(
         out_specs=pl.BlockSpec((tile_m, tile_n), lambda i, j, p, kt: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((tile_m, tile_n), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,

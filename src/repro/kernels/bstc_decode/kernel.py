@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 
 def _unpack_bits_i32(packed: jax.Array) -> jax.Array:
     """(..., B) uint8 -> (..., 8B) int32 {0,1}; little-endian within bytes."""
@@ -69,7 +67,7 @@ def bstc_decode_pallas(
         ],
         out_specs=pl.BlockSpec((tile_g, tile_k), lambda g, kt: (g, kt)),
         out_shape=jax.ShapeDtypeStruct((G, H), jnp.uint8),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 
 def _unpack_bits_i32(packed: jax.Array) -> jax.Array:
     x = packed.astype(jnp.int32)
@@ -143,7 +141,7 @@ def bstc_matmul_pallas(
         out_specs=pl.BlockSpec((tile_m, tile_n), lambda i, j, kt: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((tile_m, tile_n), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -27,7 +27,7 @@ import contextlib
 import os
 from typing import Callable, Optional
 
-from repro import compat
+import jax
 
 MODE_COMPILED = "compiled"
 MODE_INTERPRET = "interpret"
@@ -83,7 +83,7 @@ def resolve_mode(
     env = os.environ.get(ENV_VAR)
     if env:
         return _validate(env)
-    return MODE_COMPILED if compat.is_tpu_backend() else MODE_INTERPRET
+    return MODE_COMPILED if jax.default_backend() == "tpu" else MODE_INTERPRET
 
 
 def pallas_dispatch(
@@ -108,10 +108,11 @@ def pallas_dispatch(
                 f"kernel family {name!r} has no ref-fallback path"
             )
         return ref_fn(*args, **kwargs)
-    if resolved == MODE_COMPILED and not compat.is_tpu_backend():
+    backend = jax.default_backend()
+    if resolved == MODE_COMPILED and backend != "tpu":
         raise RuntimeError(
             f"kernel family {name!r}: compiled dispatch requested on "
-            f"backend {compat.default_backend()!r}; use mode='interpret' "
+            f"backend {backend!r}; use mode='interpret' "
             f"or 'ref' (or unset ${ENV_VAR}) on non-TPU hosts"
         )
     return pallas_fn(*args, interpret=resolved == MODE_INTERPRET, **kwargs)

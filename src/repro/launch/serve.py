@@ -2,7 +2,7 @@
 engine (per-slot positions, int8 / bgpp KV caches, request scheduler).
 
     PYTHONPATH=src python -m repro.launch.serve --arch phi4-mini-3.8b \\
-        --kv-format int8 --requests 8 --slots 4 --seed 0 \\
+        [--smoke] --kv-format int8 --requests 8 --slots 4 --seed 0 \\
         [--admission chunked|eager] [--chunk-budget 16] \\
         [--kv-layout slot|paged] [--page-size 8] [--shared-prefix 16] \\
         [--bgpp-rounds 4] [--bgpp-keep-ratio 0.25] \\
@@ -11,7 +11,10 @@ engine (per-slot positions, int8 / bgpp KV caches, request scheduler).
         [--server] [--disconnect-every 3] [--disconnect-after 1] \\
         [--trace-out trace.json] [--mesh 2,4 | --data 1 --model 1]
 
-Requests arrive on a Poisson-ish trace with distinct prompt lengths and
+Without ``--smoke`` the model runs at its published widths, with random
+weights drawn from ``--seed`` (phi4-mini-3.8b needs one 16 GB accelerator);
+``--smoke`` takes the config's reduced CPU variant.  Requests arrive on a
+Poisson-ish trace with distinct prompt lengths and
 decode budgets.  With the default ``--admission chunked`` the scheduler
 feeds each arriving prompt through fixed-shape, bucketed prefill chunks
 (jitted once per bucket, cache donated) interleaved with the batched decode
@@ -56,6 +59,7 @@ from __future__ import annotations
 import argparse
 import json
 import time
+from typing import Callable, Optional
 
 import numpy as np
 
@@ -67,7 +71,8 @@ from repro.configs import (ARCH_REGISTRY, WEIGHT_FORMATS,
                            apply_spec_decode_overrides,
                            apply_weight_format_override, get_config)
 from repro.distributed import sharding as sh
-from repro.launch.mesh import make_debug_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models import model_zoo
 from repro.serving import kv_cache as kvc
 from repro.serving import sharded as shd
@@ -75,10 +80,20 @@ from repro.serving.request import poisson_trace
 from repro.serving.scheduler import Scheduler
 
 
-def main():
+def device_label() -> str:
+    """``platform device_kind xcount`` of the devices JAX runs on — every
+    printed rate names the hardware it was measured on."""
+    devs = jax.devices()
+    return f"{devs[0].platform} {devs[0].device_kind} x{len(devs)}"
+
+
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", choices=sorted(ARCH_REGISTRY),
                     default="phi4-mini-3.8b")
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the config's reduced smoke variant (CPU "
+                         "tests) instead of the published widths")
     ap.add_argument("--kv-format", default="int8",
                     choices=["bf16", "int8", "bgpp"])
     ap.add_argument("--kv-layout", default="slot", choices=["slot", "paged"],
@@ -136,7 +151,8 @@ def main():
     ap.add_argument("--arrival-rate", type=float, default=2.0,
                     help="mean inter-arrival gap in decode steps")
     ap.add_argument("--seed", type=int, default=0,
-                    help="request-trace RNG seed (reproducible runs)")
+                    help="seed of the random weights and the request trace "
+                         "(reproducible runs)")
     ap.add_argument("--server", action="store_true",
                     help="drive the asyncio front door with simulated "
                          "tiered streaming clients (interactive/batch "
@@ -157,12 +173,41 @@ def main():
                          "--data/--model.  Needs data*model visible devices "
                          "(XLA_FLAGS=--xla_force_host_platform_device_count=8 "
                          "on CPU)")
-    args = ap.parse_args()
+    return ap
+
+
+def init_params(cfg, seed: int, rules: sh.ShardingRules = sh.ShardingRules()):
+    """Random weights for ``cfg`` from ``seed``, built by one jitted program
+    so each leaf is written once, in its own dtype, straight into device
+    memory (no eager per-op temporaries at published widths) — on a mesh,
+    directly in the serving placement, so no device ever holds the whole
+    model."""
+    key = jax.random.key(seed)
+    shardings = None
+    if rules.mesh is not None:
+        shapes = jax.eval_shape(lambda k: model_zoo.init_params(k, cfg), key)
+        shardings = shd.param_shardings(
+            shapes, model_zoo.param_specs(cfg), rules
+        )
+    init = jax.jit(model_zoo.init_params, static_argnums=1,
+                   out_shardings=shardings)
+    return init(key, cfg)
+
+
+def run(args, params=None, *, record_logits: bool = False,
+        on_step: Optional[Callable[[Scheduler], None]] = None):
+    """Serve one request trace as ``args`` describe -> ``(stats, sched)``.
+
+    ``params`` (else random weights from ``args.seed``) lets one process
+    serve several cache formats with the same weights; ``record_logits``
+    keeps every emitted token's logits row on its request; ``on_step`` is
+    called with the scheduler after every offline-loop step.
+    """
     if args.mesh:
         args.data, args.model = shd.parse_mesh_arg(args.mesh)
 
     cfg = apply_bgpp_overrides(
-        get_config(args.arch, smoke=True),
+        get_config(args.arch, smoke=args.smoke),
         rounds=args.bgpp_rounds, keep_ratio=args.bgpp_keep_ratio,
     )
     cfg = apply_decode_kernel_override(cfg, args.decode_kernel)
@@ -173,10 +218,11 @@ def main():
     if cfg.family not in ("dense", "moe", "vlm"):
         raise SystemExit("continuous batching driver covers transformer "
                          "families; ssm/hybrid/enc-dec decode in tests/")
-    mesh = make_debug_mesh(args.data, args.model)
+    mesh = make_mesh(args.data, args.model)
     rules = sh.rules_for_mesh(mesh)
     rng = np.random.default_rng(args.seed)
-    params, _ = model_zoo.init(jax.random.key(0), cfg)
+    if params is None:
+        params = init_params(cfg, args.seed, rules)
 
     layout = kvc.layout_for(cfg, args.slots, args.max_seq,
                             kv_format=args.kv_format,
@@ -184,7 +230,8 @@ def main():
     sched = Scheduler(params, cfg, layout, rules,
                       admission=args.admission,
                       chunk_budget=args.chunk_budget,
-                      prefill_kw=dict(block_q=16, block_k=32))
+                      prefill_kw=dict(block_q=16, block_k=32),
+                      record_logits=record_logits)
     max_prompt = min(23, args.max_seq - 2 - args.shared_prefix)
     assert max_prompt >= 1, "--shared-prefix leaves no room for prompts"
     reqs = poisson_trace(rng, args.requests, cfg.vocab_size,
@@ -211,6 +258,8 @@ def main():
         with mesh:
             while sched.num_pending:
                 sched.step()
+                if on_step is not None:
+                    on_step(sched)
                 if len(sched.finished) != done:
                     done = len(sched.finished)
                     print(f"[serve] {done}/{args.requests} requests "
@@ -222,7 +271,7 @@ def main():
           f"admission={args.admission}: "
           f"{stats['finished_requests']} requests, "
           f"{stats['decoded_tokens']} tokens in {dt:.1f}s "
-          f"({stats['tokens_per_s']:.1f} tok/s CPU smoke, "
+          f"({stats['tokens_per_s']:.1f} tok/s on {device_label()}, "
           f"mean occupancy {stats['mean_occupancy']:.2f})")
     print(f"[serve] ttft_s p50={stats['ttft_s']['p50']} "
           f"p95={stats['ttft_s']['p95']}  "
@@ -301,6 +350,7 @@ def main():
             "requests": args.requests, "max_new": args.max_new,
             "admission": args.admission, "chunk_budget": args.chunk_budget,
             "arrival_rate": args.arrival_rate, "seed": args.seed,
+            "smoke": args.smoke, "device": device_label(),
             "mesh": [args.data, args.model],
             "bgpp_rounds": cfg.mcbp.bgpp_rounds,
             "bgpp_keep_ratio": cfg.mcbp.bgpp_keep_ratio,
@@ -316,6 +366,12 @@ def main():
         with open(args.trace_out, "w") as f:
             json.dump(stats, f, indent=2)
         print(f"[serve] trace -> {args.trace_out}")
+    return stats, sched
+
+
+def main():
+    enable_compile_cache()
+    run(build_parser().parse_args())
 
 
 if __name__ == "__main__":

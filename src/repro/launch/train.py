@@ -3,17 +3,20 @@ checkpoint/restore + heartbeat + straggler monitoring + resilient loop.
 
     PYTHONPATH=src python -m repro.launch.train --arch deepseek-7b --smoke \\
         --steps 100 --batch 8 --seq-len 128 [--data 1 --model 1] \\
-        [--ckpt-dir /tmp/mcbp_train] [--int8-opt] [--fsdp]
+        [--ckpt-dir DIR] [--int8-opt] [--fsdp]
 
-On the CPU container this runs the smoke configs on a debug mesh; on a real
-cluster the same entry point takes the production mesh (launch/mesh.py) —
-every component (rules, train_step, checkpointer, pipeline) is identical to
-what the dry-run lowers for 16×16 / 2×16×16.
+``--smoke`` trains the config's reduced variant (the CPU tests); without it
+the model has its published widths.  On a real cluster the same entry point
+takes the production mesh (launch/mesh.py) — every component (rules,
+train_step, checkpointer, pipeline) is identical to what the dry-run lowers
+for 16×16 / 2×16×16.
 """
 
 from __future__ import annotations
 
 import argparse
+import os
+import tempfile
 import time
 
 import jax
@@ -23,7 +26,8 @@ from repro.checkpoint import Checkpointer
 from repro.configs import ARCH_REGISTRY, get_config
 from repro.data import Prefetcher, SyntheticLMDataset
 from repro.distributed import sharding as sh
-from repro.launch.mesh import make_debug_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models import model_zoo
 from repro.optim import AdamWConfig, adamw_init, opt_state_specs
 from repro.runtime import Heartbeat, StragglerMonitor, run_resilient
@@ -33,7 +37,9 @@ from repro.training import make_train_step
 def main():
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", choices=sorted(ARCH_REGISTRY), default="deepseek-7b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="train the config's reduced smoke variant (CPU "
+                         "tests) instead of the published widths")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq-len", type=int, default=128)
@@ -42,13 +48,16 @@ def main():
     ap.add_argument("--grad-accum", type=int, default=1)
     ap.add_argument("--int8-opt", action="store_true")
     ap.add_argument("--fsdp", action="store_true")
-    ap.add_argument("--ckpt-dir", default="/tmp/mcbp_train")
+    ap.add_argument("--ckpt-dir",
+                    default=os.path.join(tempfile.gettempdir(), "mcbp_train"))
     ap.add_argument("--ckpt-every", type=int, default=50)
-    ap.add_argument("--heartbeat", default="/tmp/mcbp_train_heartbeat.json")
+    ap.add_argument("--heartbeat", default=os.path.join(
+        tempfile.gettempdir(), "mcbp_train_heartbeat.json"))
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
-    mesh = make_debug_mesh(args.data, args.model)
+    mesh = make_mesh(args.data, args.model)
     rules = sh.rules_for_mesh(
         mesh, fsdp_axes=(sh.D_MODEL,) if args.fsdp else (), sp=args.model > 1
     )

@@ -207,7 +207,9 @@ def _bgpp_topk_indices(qf, plane0, sign_full, plane_at, valid, cfg):
     cur_idx = None  # None = all S keys
     for r in range(1, rounds):
         k_r = survivors[r]
-        _, li = jax.lax.top_k(score_h, k_r)  # local ids in the current set
+        # local ids in the current set, kept in ascending logical order so
+        # every round breaks score ties toward the lowest logical index
+        li = jnp.sort(jax.lax.top_k(score_h, k_r)[1], axis=-1)
         cur_idx = li if cur_idx is None else jnp.take_along_axis(cur_idx, li, axis=2)
         partial = jnp.take_along_axis(partial, li[:, :, None, :], axis=3)
         p_r = kvc.NBITS - 1 - r
@@ -227,7 +229,7 @@ def _bgpp_topk_indices(qf, plane0, sign_full, plane_at, valid, cfg):
         )
 
     # ---- the final k_max candidate set -----------------------------------
-    _, li = jax.lax.top_k(score_h, k_max)
+    li = jnp.sort(jax.lax.top_k(score_h, k_max)[1], axis=-1)
     idx = li if cur_idx is None else jnp.take_along_axis(cur_idx, li, axis=2)
     idx_valid = jnp.take_along_axis(
         jnp.broadcast_to(valid[:, None, :], (B, Hk, S)), idx, axis=2
@@ -362,15 +364,13 @@ def _bgpp_paged_decode_attend_sharded(q, store, gi, phys, valid, cfg, layout,
     m = dict(mesh.shape).get(rules.model_axis, 1)
     if m <= 1 or cfg.num_kv_heads % m or cfg.num_heads % m:
         return run(q, store, phys, valid)
-    from jax.experimental.shard_map import shard_map
-
     spec = lambda axes, x: rules.spec_for_shape(mesh, axes, x.shape)
     store_spec = jax.tree.map(
         lambda axes, x: spec(tuple(axes), x),
         kvc.cache_specs(cfg, layout)["global"], store,
         is_leaf=lambda x: isinstance(x, tuple),
     )
-    return shard_map(
+    return jax.shard_map(
         run, mesh=mesh,
         in_specs=(
             spec((sh.BATCH, sh.HEADS, None), q),
@@ -379,7 +379,7 @@ def _bgpp_paged_decode_attend_sharded(q, store, gi, phys, valid, cfg, layout,
             spec((sh.BATCH, None), valid),
         ),
         out_specs=spec((sh.BATCH, sh.HEADS, None), q),
-        check_rep=False,
+        check_vma=False,
     )(q, store, phys, valid)
 
 
@@ -457,7 +457,7 @@ def _attn_decode_layer(p, cfg, layout, cache, x, pos, layer_idx, theta, rules,
             if decode_mode != "jnp":
                 out = kernel_decode.decode_attend(
                     q[:, 0], cache["global"], gi, pos, cfg, layout, rules,
-                    decode_mode, phys=phys, page_table=cache["page_table"],
+                    decode_mode, page_table=cache["page_table"],
                 )
             if out is None and fmt == "bgpp":
                 # two-phase attend: bit-planes first, then only the top-k

@@ -9,10 +9,11 @@ branch) has two kernel-backed formats:
   table STRAIGHT into the kernel (the BlockSpec index map gathers
   physical pages; ``kv_cache.paged_entry``'s contiguous per-slot view is
   never built).  The slot layout pool-ifies its heads-major stacks with
-  free transposes and an identity page table, so one kernel serves both.
+  transposes and an identity page table (one page per slot), so one
+  kernel serves both.
 * ``bgpp_paged_attend`` — the fused two-phase BGPP decode (plane scan,
-  progressive top-k, compacted survivor gather, exact int8 attend) in
-  one launch.
+  progressive top-k, exact int8 attend over the survivors) in one launch,
+  fed the same way.
 
 Mode resolution happens ONCE at ``make_serve_step`` build time
 (:func:`resolve`): the ``decode_kernel`` config knob (or the
@@ -38,7 +39,6 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.distributed import sharding as sh
 from repro.kernels import MODE_COMPILED, MODE_INTERPRET
 from repro.kernels.bgpp_paged_attend import bgpp_paged_attend
@@ -72,17 +72,8 @@ def resolve(cfg) -> str:
             f"mcbp.decode_kernel or ${ENV_VAR})"
         )
     if knob == "auto":
-        knob = "kernel" if compat.is_tpu_backend() else "jnp"
+        knob = "kernel" if jax.default_backend() == "tpu" else "jnp"
     return _EXEC[knob]
-
-
-def _slot_page_size(max_seq: int) -> int:
-    """Largest of 8/4/2/1 dividing ``max_seq`` — the identity-page-table
-    page size used to pool-ify slot stacks (always succeeds; 1 divides)."""
-    for p in (8, 4, 2, 1):
-        if max_seq % p == 0:
-            return p
-    raise AssertionError("unreachable: 1 divides everything")
 
 
 def validate(cfg, layout) -> None:
@@ -98,9 +89,9 @@ def validate(cfg, layout) -> None:
             f"num_kv_heads={cfg.num_kv_heads} — the GQA group size must be "
             f"integral for the grouped (B, Hk, g, Dh) kernel query layout"
         )
-    # NOTE: max_seq need not be page-aligned — the flash kernel attends the
-    # full page-covered span (pages_per_slot * page_size lanes) and masks
-    # past pos exactly like the engine, and the bgpp phys map is row-level.
+    # NOTE: max_seq need not be page-aligned — both kernels stage the full
+    # page-covered span (pages_per_slot * page_size lanes) and mask lanes
+    # past pos (bgpp: past the plan's context too) exactly like the engine.
     if layout.kv_format == "bgpp":
         if cfg.head_dim % 8:
             raise ValueError(
@@ -124,7 +115,8 @@ def _pool_views(store: Tree, gi: int, fmt: str, slot_layout: bool) -> Tree:
     layer.  Slot stores are heads-major ``(B, Hk, S, ...)`` stacks; the
     transposes below re-lay them as ``(B*S, Hk, ...)`` pools whose row
     ``b*S + s`` is slot ``b``'s logical position ``s`` (an identity page
-    table / phys map addresses them), so both layouts feed one kernel.
+    table of one ``S``-row page per slot addresses them), so both layouts
+    feed one kernel.
     """
     if not slot_layout:
         if fmt == "bgpp":
@@ -150,38 +142,31 @@ def _pool_views(store: Tree, gi: int, fmt: str, slot_layout: bool) -> Tree:
 def _attend_local(q1, pool: Tree, pos, table, cfg, layout, mode: str):
     """Run the kernel family on device-local operands -> ``(B, Hq, Dh)``.
 
-    ``table`` is the page table (non-bgpp) or the phys map (bgpp) — for
-    the slot layout the caller passes ``None`` and identity maps are built
-    here from the LOCAL batch size, so the same body serves the
-    ``shard_map``-wrapped and unsharded calls.
+    ``table`` is the page table — for the slot layout the caller passes
+    ``None`` and each slot's ``S`` rows of the pool-ified stack form one
+    page (an identity table built here from the LOCAL batch size), so the
+    same body serves the ``shard_map``-wrapped and unsharded calls.
     """
     B, Hq, Dh = q1.shape
     g = cfg.num_heads // cfg.num_kv_heads  # ratio: shard-invariant
     Hk = Hq // g
     qg = q1.reshape(B, Hk, g, Dh).astype(jnp.float32)
     fmt = layout.kv_format
-    slot = layout.layout != "paged"
     S = layout.max_seq
+    if table is None:  # slot: slot b's rows [b*S, (b+1)*S) are its one page
+        table = jnp.arange(B, dtype=jnp.int32)[:, None]
+        page_size = S
+    else:
+        page_size = layout.page_size
 
     if fmt == "bgpp":
-        if table is None:  # slot: identity logical->pool row map
-            table = (jnp.arange(B, dtype=jnp.int32)[:, None] * S
-                     + jnp.arange(S, dtype=jnp.int32)[None, :])
         rounds, k_max, survivors = kvc.bgpp_decode_plan(S, cfg)
         out = bgpp_paged_attend(
             qg, pool["k_planes"], pool["k_sign"], pool["k_scale"],
-            pool["v"], pool["v_scale"], table, pos,
+            pool["v"], pool["v_scale"], table, pos, page_size=page_size,
             rounds=rounds, k_max=k_max, survivors=survivors, mode=mode,
         )
     else:
-        if table is None:  # slot: identity page table over the B*S pool
-            P = _slot_page_size(S)
-            pp = S // P
-            table = (jnp.arange(B, dtype=jnp.int32)[:, None] * pp
-                     + jnp.arange(pp, dtype=jnp.int32)[None, :])
-            page_size = P
-        else:
-            page_size = layout.page_size
         scales = (
             {} if fmt == "bf16"
             else {"k_scale": pool["k_scale"], "v_scale": pool["v_scale"]}
@@ -191,12 +176,12 @@ def _attend_local(q1, pool: Tree, pos, table, cfg, layout, mode: str):
             page_size=page_size, mode=mode, **scales,
         )
     # (B, Hk, g, Dh) -> (B, Hq, Dh): same axis order as the engine's
-    # transpose/reshape epilogue (verified bitwise in the parity tests)
+    # transpose/reshape epilogue
     return out.reshape(B, Hq, Dh)
 
 
 def decode_attend(q1, store: Tree, gi: int, pos, cfg, layout, rules,
-                  mode: str, phys=None, page_table=None):
+                  mode: str, page_table=None):
     """Kernel-backed global-layer decode attend, or ``None`` to fall back.
 
     q1 ``(B, Hq, Dh)``; ``store`` is ``cache["global"]``; ``pos`` the
@@ -210,7 +195,7 @@ def decode_attend(q1, store: Tree, gi: int, pos, cfg, layout, rules,
         return None
     fmt = layout.kv_format
     slot = layout.layout != "paged"
-    table = None if slot else (phys if fmt == "bgpp" else page_table)
+    table = None if slot else page_table
     pos = pos.astype(jnp.int32)
 
     mesh = getattr(rules, "mesh", None)
@@ -222,8 +207,6 @@ def decode_attend(q1, store: Tree, gi: int, pos, cfg, layout, rules,
         return None  # heads don't shard: engine jnp fallback (replicated)
     if slot and (getattr(rules, "seq_shard", False) or getattr(rules, "sp", False)):
         return None  # seq-sharded slot stacks break the identity pool maps
-    from jax.experimental.shard_map import shard_map
-
     def run(q_, store_, pos_, table_):
         pool = _pool_views(store_, gi, fmt, slot)
         t = table_ if not slot else None
@@ -237,7 +220,7 @@ def decode_attend(q1, store: Tree, gi: int, pos, cfg, layout, rules,
     )
     if table is None:  # slot layout: feed a dummy all-devices scalar map
         table = jnp.zeros((q1.shape[0], 1), jnp.int32)
-    return shard_map(
+    return jax.shard_map(
         run, mesh=mesh,
         in_specs=(
             spec((sh.BATCH, sh.HEADS, None), q1),
@@ -246,5 +229,5 @@ def decode_attend(q1, store: Tree, gi: int, pos, cfg, layout, rules,
             spec((sh.BATCH, None), table),
         ),
         out_specs=spec((sh.BATCH, sh.HEADS, None), q1),
-        check_rep=False,
+        check_vma=False,
     )(q1, store, pos, table)

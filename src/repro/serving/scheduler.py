@@ -156,11 +156,11 @@ class Scheduler:
         if rules.mesh is not None:
             self.cache = shd.shard_cache(self.cache, cfg, layout, rules)
             if param_specs is None:
+                # a family without a spec tree raises here: replicating the
+                # weights instead would silently put the whole model on
+                # every device of the mesh
                 from repro.models import model_zoo
-                try:
-                    param_specs = model_zoo.param_specs(cfg)
-                except Exception:
-                    param_specs = None  # unknown tree: replicate (still exact)
+                param_specs = model_zoo.param_specs(cfg)
             self.params = shd.shard_params(params, param_specs, rules)
         # serve-time weight format (the once-dead knob): resolved ONCE here
         # (env > config, same contract as decode_kernel), projections

@@ -33,9 +33,10 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.distributed import sharding as sh
+from repro.launch.mesh import make_mesh
 from repro.serving import kv_cache as kvc
 
 # logical axes that may shard a weight only when they are the OUTPUT axis
@@ -80,20 +81,17 @@ def serving_param_specs(specs):
 def shard_params(params, specs, rules: sh.ShardingRules):
     """Place params on the rules' mesh under the serving policy.
 
-    ``specs`` is the model zoo's logical tree (``model_zoo.param_specs``);
-    ``None`` replicates everything (still a valid, if traffic-heavy,
-    bit-exact placement).  No-op without a mesh.
+    ``specs`` is the model zoo's logical tree (``model_zoo.param_specs``).
     """
-    mesh = rules.mesh
-    if mesh is None:
-        return params
-    if specs is None:
-        rep = NamedSharding(mesh, P())
-        return jax.device_put(params, jax.tree.map(lambda _: rep, params))
-    shardings = rules.tree_shardings(
-        mesh, serving_param_specs(specs), struct_tree=params
+    return jax.device_put(params, param_shardings(params, specs, rules))
+
+
+def param_shardings(params, specs, rules: sh.ShardingRules):
+    """NamedShardings of the serving policy for ``params`` (arrays or
+    ``ShapeDtypeStruct``s) on the rules' mesh."""
+    return rules.tree_shardings(
+        rules.mesh, serving_param_specs(specs), struct_tree=params
     )
-    return jax.device_put(params, shardings)
 
 
 def cache_shardings(cache, cfg, layout, rules: sh.ShardingRules):
@@ -124,20 +122,6 @@ def replicated(x, rules: sh.ShardingRules):
     return jax.device_put(np.asarray(x), NamedSharding(rules.mesh, P()))
 
 
-def make_mesh(data: int, model: int) -> Mesh:
-    """A ``("data", "model")`` mesh over the first ``data*model`` devices."""
-    n = data * model
-    avail = jax.device_count()
-    if n > avail:
-        raise ValueError(
-            f"mesh {data}x{model} needs {n} devices, have {avail} — on CPU "
-            "set XLA_FLAGS=--xla_force_host_platform_device_count="
-            f"{n} before importing jax"
-        )
-    devs = np.asarray(jax.devices()[:n]).reshape(data, model)
-    return Mesh(devs, ("data", "model"))
-
-
 def parse_mesh_arg(arg: Optional[str]) -> Tuple[int, int]:
     """``"2,4"`` (or ``"2x4"``) → ``(2, 4)``; ``None``/empty → ``(1, 1)``."""
     if not arg:
@@ -152,5 +136,5 @@ def parse_mesh_arg(arg: Optional[str]) -> Tuple[int, int]:
 
 
 def rules_for(data: int, model: int) -> sh.ShardingRules:
-    """Serving rules over a fresh ``(data, model)`` debug mesh."""
+    """Serving rules over a fresh ``(data, model)`` mesh."""
     return sh.rules_for_mesh(make_mesh(data, model))

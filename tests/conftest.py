@@ -36,10 +36,11 @@ def _interpret_dispatch_without_tpu():
     An explicit ``REPRO_KERNEL_DISPATCH`` (e.g. ``ref`` for a fast oracle
     sweep, or ``compiled`` on a real TPU host) wins over this default.
     """
-    from repro import compat
+    import jax
+
     from repro.kernels import dispatch
 
-    if os.environ.get(dispatch.ENV_VAR) or compat.is_tpu_backend():
+    if os.environ.get(dispatch.ENV_VAR) or jax.default_backend() == "tpu":
         yield
         return
     dispatch.set_default_mode(dispatch.MODE_INTERPRET)

@@ -6,7 +6,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.analysis.hlo import HloModule
 from repro.analysis.roofline import (
     HW,
@@ -78,7 +77,7 @@ class TestHloTextModel:
         b = jnp.zeros((128, 256), jnp.float32)
         compiled = jax.jit(lambda a, b: a @ b).lower(a, b).compile()
         mod = HloModule(compiled.as_text())
-        xla = compat.cost_analysis_dict(compiled)["flops"]
+        xla = compiled.cost_analysis()["flops"]
         assert abs(mod.dot_flops() - xla) / xla < 0.01
 
     def test_loop_flops_corrected_vs_xla(self):
@@ -95,7 +94,7 @@ class TestHloTextModel:
 
         compiled = jax.jit(f).lower(w, x).compile()
         mod = HloModule(compiled.as_text())
-        xla = compat.cost_analysis_dict(compiled)["flops"]
+        xla = compiled.cost_analysis()["flops"]
         ratio = mod.dot_flops() / max(xla, 1)
         assert 4.0 < ratio <= 9.0, ratio  # ~8 iterations
 

@@ -213,10 +213,10 @@ class TestServeStepAccessStructure:
         with ``decode_kernel=interpret`` the global attend becomes a
         ``pallas_call`` over the token-major pools, and the traced step —
         including every sub-jaxpr the interpreter carries — must still
-        never hold a full-width int8 KV row.  The kernel consumes packed
-        planes and gathers k_max compacted rows per (b, h) cell, so a
-        full-row aval appearing here means the dispatch path regressed to
-        a dense-entry gather."""
+        never hold a full-width int8 KV row.  The kernel stages a slot's
+        pages (packed planes and int8 V as ``(S, Hk*...)`` rows) inside
+        its own VMEM, so a heads-split full-row aval appearing here means
+        the dispatch path regressed to an XLA dense-entry gather."""
         from repro.models import model_zoo
         from repro.serving import kernel_decode
 

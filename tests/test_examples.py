@@ -50,7 +50,8 @@ class TestExamples:
 class TestLaunchers:
     def test_train_launcher(self, tmp_path):
         out = run_example([
-            "-m", "repro.launch.train", "--steps", "20", "--batch", "2",
+            "-m", "repro.launch.train", "--smoke", "--steps", "20",
+            "--batch", "2",
             "--seq-len", "32", "--ckpt-every", "10",
             "--ckpt-dir", str(tmp_path / "ck"),
             "--heartbeat", str(tmp_path / "hb.json"),
@@ -59,7 +60,7 @@ class TestLaunchers:
 
     def test_serve_launcher(self):
         out = run_example([
-            "-m", "repro.launch.serve", "--requests", "2", "--slots", "2",
-            "--max-new", "4",
+            "-m", "repro.launch.serve", "--smoke", "--requests", "2",
+            "--slots", "2", "--max-new", "4",
         ])
         assert "2/2 requests" in out

@@ -469,7 +469,7 @@ class TestSpecDecodeAccountingLaws:
 
 
 class TestDispatchRoundTripLaws:
-    """Round-trip laws for the compat-routed kernel dispatch paths.
+    """Round-trip laws for the kernel dispatch paths.
 
     Small shapes + few examples keep these inside the tier-1 budget; the
     exhaustive tiling sweeps live in tests/test_kernel_*.py and

@@ -238,3 +238,20 @@ class TestSlotIsolation:
         for rid in range(3):
             alone = run([rid])
             assert joint[rid] == alone[rid], f"request {rid} not isolated"
+
+
+def test_mesh_without_param_specs_raises(served, monkeypatch):
+    """On a mesh the weights are placed from the model's spec tree; a
+    family without one raises instead of replicating the whole model on
+    every device."""
+    from repro.serving import sharded as shd
+
+    cfg, params = served
+
+    def no_specs(cfg_):
+        raise ValueError(f"no param specs for family {cfg_.family!r}")
+
+    monkeypatch.setattr(model_zoo, "param_specs", no_specs)
+    layout = kvc.layout_for(cfg, 2, MAX_SEQ, kv_format="int8")
+    with pytest.raises(ValueError, match="no param specs"):
+        Scheduler(params, cfg, layout, shd.rules_for(1, 1))

@@ -136,3 +136,36 @@ class TestRulesForMesh:
         mesh2 = Mesh(devs.reshape(1, 1), ("data", "model"))
         r2 = sh.rules_for_mesh(mesh2)
         assert r2.batch_axes == ("data",)
+
+
+class TestAmbientMesh:
+    def test_none_outside_any_mesh_context(self):
+        assert sh.ambient_mesh() is None
+
+    def test_ambient_mesh_is_discovered(self):
+        mesh = Mesh(np.asarray(jax.devices()[:1]), ("data",))
+        with mesh:
+            found = sh.ambient_mesh()
+        assert found is not None
+        assert "data" in found.axis_names
+
+    def test_constrain_is_noop_without_mesh(self):
+        import jax.numpy as jnp
+
+        x = jnp.ones((4, 8))
+        out = sh.constrain(x, sh.ShardingRules(), (sh.BATCH, None))
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(x))
+
+    def test_constrain_inside_jit_under_ambient_mesh(self):
+        import jax.numpy as jnp
+
+        mesh = Mesh(np.asarray(jax.devices()[:1]), ("data",))
+        rules = sh.ShardingRules()
+
+        @jax.jit
+        def f(x):
+            return sh.constrain(x, rules, (sh.BATCH, None)) * 2.0
+
+        with mesh:
+            out = f(jnp.ones((4, 8)))
+        np.testing.assert_array_equal(np.asarray(out), np.full((4, 8), 2.0))

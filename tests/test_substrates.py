@@ -10,7 +10,6 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.checkpoint import Checkpointer
 from repro.configs import get_config
 from repro.data import Prefetcher, SyntheticLMDataset
@@ -207,7 +206,7 @@ class TestGradientCompression:
         rng = np.random.default_rng(0)
         g = jnp.asarray(rng.normal(size=(2, 64)), jnp.float32)
 
-        f = compat.shard_map(
+        f = jax.shard_map(
             lambda x: compressed_psum_mean({"g": x}, "data")["g"],
             mesh=mesh, in_specs=P("data"), out_specs=P("data"),
             check_vma=False,
