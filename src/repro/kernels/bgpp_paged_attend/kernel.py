@@ -309,6 +309,7 @@ def bgpp_paged_attend_pallas(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="bgpp_paged_attend",
     )(pids, pos.astype(jnp.int32),
       _bit_major(qf.astype(jnp.float32)), _bit_major(q_q),
       dq,

@@ -186,4 +186,5 @@ def paged_flash_decode_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="paged_flash_decode",
     )(pids, pos.astype(jnp.int32), *operands)

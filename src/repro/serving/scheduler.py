@@ -87,6 +87,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.distributed import sharding as sh
 from repro.serving import engine, kv_cache as kvc
@@ -95,6 +96,30 @@ from repro.serving import spec_decode as spd
 from repro.serving import weights as swt
 from repro.serving.paging import PageAllocator
 from repro.serving.request import (Request, Slot, SlotState, priority_rank)
+
+
+# Host spans that ``step()`` writes on the profiler's clock, one at each
+# layer boundary, with the arguments each carries:
+#   sched.step           all of step()                            step, queued
+#   sched.admit          shedding and admission
+#   sched.prefill_chunk  a chunk's (or eager prompt's) host padding and
+#                        dispatch                  rid, slot, offset, tokens
+#   sched.first_token    pull of the prompt's last logits row, pick, hook  rid
+#   sched.sync_pages     page-table push, when the table changed
+#   sched.decode         token feed and serve_step dispatch         decoding
+#   sched.logits         last-position logits to the host as f32
+#   sched.sample         per-slot pick, hooks and finishes
+#   sched.spec_round     one speculative round
+# While no profiler runs, a span costs one enter and exit, about 2 us.
+SPAN_STEP = "sched.step"
+SPAN_ADMIT = "sched.admit"
+SPAN_PREFILL_CHUNK = "sched.prefill_chunk"
+SPAN_FIRST_TOKEN = "sched.first_token"
+SPAN_SYNC_PAGES = "sched.sync_pages"
+SPAN_DECODE = "sched.decode"
+SPAN_LOGITS = "sched.logits"
+SPAN_SAMPLE = "sched.sample"
+SPAN_SPEC_ROUND = "sched.spec_round"
 
 
 def greedy_sample(logits: np.ndarray) -> np.ndarray:
@@ -318,9 +343,10 @@ class Scheduler:
         """Push the host page table to the (replicated) device copy if it
         changed — the allocator itself never leaves the host."""
         if self.pager is not None and self.pager.dirty:
-            self.cache["page_table"] = shd.replicated(
-                self.pager.table, self.rules
-            )
+            with TraceAnnotation(SPAN_SYNC_PAGES):
+                self.cache["page_table"] = shd.replicated(
+                    self.pager.table, self.rules
+                )
             self.pager.dirty = False
 
     def _ensure_pages(self, slot: int, lo: int, hi: int) -> None:
@@ -462,12 +488,17 @@ class Scheduler:
             req.admit_time = time.perf_counter()
             self.prompt_tokens_admitted += req.prompt_len
             self._ensure_pages(slot.index, 0, req.prompt_len)
-            logits, self.cache = engine.prefill_into_slot(
-                self.params, self.cfg, self.layout, self.cache, slot.index,
-                jnp.asarray(req.prompt, jnp.int32), self.rules,
-                **self.prefill_kw,
-            )
-            self._emit_first_token(slot, np.asarray(logits[0, -1], np.float32))
+            with TraceAnnotation(SPAN_PREFILL_CHUNK, rid=req.rid,
+                                 slot=slot.index, offset=0,
+                                 tokens=req.prompt_len):
+                logits, self.cache = engine.prefill_into_slot(
+                    self.params, self.cfg, self.layout, self.cache,
+                    slot.index, jnp.asarray(req.prompt, jnp.int32),
+                    self.rules, **self.prefill_kw,
+                )
+            with TraceAnnotation(SPAN_FIRST_TOKEN, rid=req.rid):
+                self._emit_first_token(
+                    slot, np.asarray(logits[0, -1], np.float32))
             self.weight_bytes_read["prefill"] += self.weight_plan.bf16_bytes
             admitted.append(req)
         return admitted
@@ -526,11 +557,14 @@ class Scheduler:
                        self.chunked.buckets[-1])  # custom buckets < budget
             self._ensure_pages(slot.index, slot.prefill_pos,
                                slot.prefill_pos + take)
-            logits, self.cache, n = self.chunked.run_chunk(
-                self.params, self.cache, slot.index,
-                req.prompt[slot.prefill_pos:slot.prefill_pos + take],
-                slot.prefill_pos,
-            )
+            with TraceAnnotation(SPAN_PREFILL_CHUNK, rid=req.rid,
+                                 slot=slot.index, offset=slot.prefill_pos,
+                                 tokens=take):
+                logits, self.cache, n = self.chunked.run_chunk(
+                    self.params, self.cache, slot.index,
+                    req.prompt[slot.prefill_pos:slot.prefill_pos + take],
+                    slot.prefill_pos,
+                )
             self.kv_bytes_read["prefill"] += self._chunk_read["total"]
             self.kv_bytes_read["interconnect"] += self._chunk_ic_per_lane * n
             # chunk forwards read the raw-dtype tree once per chunk step
@@ -543,7 +577,9 @@ class Scheduler:
             self.pager.register_prefix(slot.index, req.prompt,
                                        slot.prefill_pos)
         if slot.prefill_pos >= req.prompt_len:
-            self._emit_first_token(slot, np.asarray(logits[0, n - 1], np.float32))
+            with TraceAnnotation(SPAN_FIRST_TOKEN, rid=req.rid):
+                self._emit_first_token(
+                    slot, np.asarray(logits[0, n - 1], np.float32))
             self._advancing = None
         else:
             self._advancing = slot.index
@@ -867,11 +903,17 @@ class Scheduler:
         Returns False when there was nothing to do (no live slot and no
         admissible request) — the caller's idle/termination signal.
         """
-        self._shed_expired()
-        if self.admission == "chunked":
-            spent = self._advance_admission()
-        else:
-            spent = sum(r.prompt_len for r in self.admit())
+        with TraceAnnotation(SPAN_STEP, step=self.step_count,
+                             queued=len(self.queue)):
+            return self._step()
+
+    def _step(self) -> bool:
+        with TraceAnnotation(SPAN_ADMIT):
+            self._shed_expired()
+            if self.admission == "chunked":
+                spent = self._advance_admission()
+            else:
+                spent = sum(r.prompt_len for r in self.admit())
         self.prefill_tokens_per_step.append(spent)
         busy = [s for s in self.slots if s.live]
         live = [s for s in self.slots if s.state is SlotState.DECODING]
@@ -883,7 +925,8 @@ class Scheduler:
             # one draft -> verify -> accept/rollback round replaces the
             # single batched decode step (same harvesting, same eviction)
             self.step_count += 1
-            self._spec_round(live)
+            with TraceAnnotation(SPAN_SPEC_ROUND):
+                self._spec_round(live)
             return True
         if self.pager is not None:
             for slot in live:
@@ -893,10 +936,12 @@ class Scheduler:
                 p = r.prompt_len + len(r.generated) - 1
                 self.pager.ensure_range(slot.index, p, p + 1)
             self._sync_pages()
-        logits, self.cache = self.serve_step(
-            self.serve_params, self.cache, jnp.asarray(self.tokens)
-        )
-        rows = np.asarray(logits[:, -1], np.float32)
+        with TraceAnnotation(SPAN_DECODE, decoding=len(live)):
+            logits, self.cache = self.serve_step(
+                self.serve_params, self.cache, jnp.asarray(self.tokens)
+            )
+        with TraceAnnotation(SPAN_LOGITS):
+            rows = np.asarray(logits[:, -1], np.float32)
         self.step_count += 1
         self.decode_steps += 1
         self.kv_bytes_read["decode"] += self._decode_read["total"]
@@ -904,17 +949,18 @@ class Scheduler:
             self._decode_read["interconnect"]["total"]
         self.weight_bytes_read["decode"] += self._weight_read["total"]
         self.decoded_tokens += len(live)
-        now = time.perf_counter()
-        for slot in live:
-            req = slot.request
-            tok = self._pick_token(req, rows[slot.index])
-            req.generated.append(tok)
-            req.token_times.append(now)
-            self.tokens[slot.index, 0] = tok
-            if req.on_token is not None:
-                req.on_token(req, tok)
-            if self._hit_limit(slot, req):
-                self._finish(slot)
+        with TraceAnnotation(SPAN_SAMPLE):
+            now = time.perf_counter()
+            for slot in live:
+                req = slot.request
+                tok = self._pick_token(req, rows[slot.index])
+                req.generated.append(tok)
+                req.token_times.append(now)
+                self.tokens[slot.index, 0] = tok
+                if req.on_token is not None:
+                    req.on_token(req, tok)
+                if self._hit_limit(slot, req):
+                    self._finish(slot)
         return True
 
     def run(self, max_steps: Optional[int] = None) -> Dict:

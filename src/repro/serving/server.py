@@ -126,7 +126,6 @@ class AsyncServer:
         self.sessions: Dict[str, ChatSession] = {}
         self._closed = False
         self._work = asyncio.Event()
-        self.steps_pumped = 0
 
     # ------------------------------------------------------------------
     # submission / streaming
@@ -239,7 +238,6 @@ class AsyncServer:
         while not self._closed:
             if self.sched.num_pending:
                 self.sched.step()
-                self.steps_pumped += 1
                 if self.check_invariants and self.sched.pager is not None:
                     self.sched.pager.check()
                 # step boundary: let clients submit / cancel / consume
@@ -267,7 +265,6 @@ class AsyncServer:
         """Scheduler stats plus server-level columns."""
         out = self.sched.stats()
         out["server"] = {
-            "steps_pumped": self.steps_pumped,
             "open_streams": len(self._streams),
             "open_sessions": len(self.sessions),
         }
