@@ -46,7 +46,7 @@ def _split_heads(x, B, H, Dh):
 
 def _cache_attend(
     q,  # (B, Q, Hq, Dh) — Q query tokens per batch row
-    entry: Tree,  # cache stack slices for this layer — heads-major (B,Hk,S,D)
+    entry: Tree,  # this layer's heads-major (B,Hk,S,D) view (slot_entry)
     valid,  # (B, Q, S) bool per-query key masks
     cfg,
     fmt: str,
@@ -54,7 +54,8 @@ def _cache_attend(
 ):
     """Attention over the heads-major cache for Q query tokens per row.
 
-    Heads-major layout (A1) avoids cache transposes; the int8 format runs
+    The entry is one layer's heads-major view (``kv_cache.slot_entry`` or
+    ``kv_cache.paged_entry``; §Perf iteration A1); the int8 format runs
     the paper-faithful 8-bit QK^T (A2) and 8-bit PV (A3) as int8 MXU dots,
     so the cache is consumed directly with no dequantized copies.  Decode
     calls it with Q=1; chunked prefill with Q=chunk — the key axis is the
@@ -442,9 +443,9 @@ def _attn_decode_layer(p, cfg, layout, cache, x, pos, layer_idx, theta, rules,
             valid = (abs_pos >= 0) & (abs_pos // w == pos_c // w) & (abs_pos <= pos_c)
         else:
             valid = (abs_pos >= 0) & (pos_c - abs_pos < w) & (abs_pos <= pos_c)
-        entry = {n: store[n][li] for n in store if n != "abs_pos"}
         fmt_l = "int8" if "k_scale" in store else "bf16"
-        out = _decode_attend(q[:, 0], entry, valid, cfg, fmt_l)
+        out = _decode_attend(q[:, 0], kvc.slot_entry(store, li), valid, cfg,
+                             fmt_l)
     else:
         gi = layout.global_layers.index(layer_idx)
         valid = jnp.arange(layout.max_seq)[None, :] <= pos_c  # (B, S)
@@ -485,8 +486,7 @@ def _attn_decode_layer(p, cfg, layout, cache, x, pos, layer_idx, theta, rules,
                     decode_mode,
                 )
             if out is None:
-                store = cache["global"]
-                entry = {n: store[n][gi] for n in store}
+                entry = kvc.slot_entry(cache["global"], gi)
                 if fmt == "bgpp":
                     out = _bgpp_decode_attend(q[:, 0], entry, valid, cfg)
                 else:
@@ -699,6 +699,14 @@ def make_serve_step(cfg, layout: kvc.CacheLayout, rules=sh.ShardingRules()):
     return serve_step
 
 
+def jit_serve_step(cfg, layout: kvc.CacheLayout, rules=sh.ShardingRules()):
+    """The jitted :func:`make_serve_step` the scheduler drives, with the
+    cache donated: the step's KV writes update it in place, so one cache is
+    resident and none is copied into a fresh output.  Callers rebind their
+    cache to the step's result and never touch the one they passed."""
+    return jax.jit(make_serve_step(cfg, layout, rules), donate_argnums=(1,))
+
+
 # --------------------------------------------------------------------------
 # prefill (build the cache from a prompt) — transformer families
 # --------------------------------------------------------------------------
@@ -831,14 +839,13 @@ def _chunk_attend_local(cfg, layout, store, li, slot, q, k, v, qpos, offset,
     g = Hq // Hk
     W = layout.local_window
 
-    if "k_scale" in store:
-        kr = store["k"][li, slot].astype(jnp.float32) \
-            * store["k_scale"][li, slot][..., None]
-        vr = store["v"][li, slot].astype(jnp.float32) \
-            * store["v_scale"][li, slot][..., None]
+    ring = {n: a[0] for n, a in kvc.slot_entry(store, li, slot).items()}
+    if "k_scale" in ring:  # heads-major (Hk, W, ...)
+        kr = ring["k"].astype(jnp.float32) * ring["k_scale"][..., None]
+        vr = ring["v"].astype(jnp.float32) * ring["v_scale"][..., None]
     else:
-        kr = store["k"][li, slot].astype(jnp.float32)
-        vr = store["v"][li, slot].astype(jnp.float32)
+        kr = ring["k"].astype(jnp.float32)
+        vr = ring["v"].astype(jnp.float32)
     ap = store["abs_pos"][li, slot]  # (W,)
 
     # reorder ring lanes to ascending position: lane j holds offset-W+j
@@ -920,12 +927,7 @@ def _attn_chunk_layer(p, cfg, layout, cache, x, slot, offset, length,
                 cache["global"], gi, k, v, slot=slot, offset=offset,
                 length=length,
             )
-            store = cache["global"]
-            view = {
-                n: (store[n][gi][:, slot][:, None] if n == "k_planes"
-                    else store[n][gi, slot][None])
-                for n in store
-            }
+            view = kvc.slot_entry(cache["global"], gi, slot)
         S = layout.max_seq
         valid = (jnp.arange(S)[None, :] <= qpos[:, None])[None]  # (1, C, S)
         if fmt == "bgpp":

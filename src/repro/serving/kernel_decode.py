@@ -8,9 +8,9 @@ branch) has two kernel-backed formats:
   passes its token-major pools and the ``(B, S_max/page_size)`` page
   table STRAIGHT into the kernel (the BlockSpec index map gathers
   physical pages; ``kv_cache.paged_entry``'s contiguous per-slot view is
-  never built).  The slot layout pool-ifies its heads-major stacks with
-  transposes and an identity page table (one page per slot), so one
-  kernel serves both.
+  never built).  The slot layout's token-major K/V stacks are the same
+  rows with a batch dim: reshaped into pools and addressed by an identity
+  page table (one page per slot), so one kernel serves both.
 * ``bgpp_paged_attend`` — the fused two-phase BGPP decode (plane scan,
   progressive top-k, exact int8 attend over the survivors) in one launch,
   fed the same way.
@@ -112,11 +112,11 @@ def _pool_views(store: Tree, gi: int, fmt: str, slot_layout: bool) -> Tree:
     """Layer ``gi``'s token-major pool leaves.
 
     Paged stores already hold token-major pools — this just indexes the
-    layer.  Slot stores are heads-major ``(B, Hk, S, ...)`` stacks; the
-    transposes below re-lay them as ``(B*S, Hk, ...)`` pools whose row
+    layer.  Slot stores become ``(B*S, Hk, ...)`` pools whose row
     ``b*S + s`` is slot ``b``'s logical position ``s`` (an identity page
     table of one ``S``-row page per slot addresses them), so both layouts
-    feed one kernel.
+    feed one kernel: token-major K/V bodies ``(B, S, Hk, D)`` by a reshape,
+    the heads-major leaves (scales, the bgpp store) by a transpose.
     """
     if not slot_layout:
         if fmt == "bgpp":
@@ -130,6 +130,9 @@ def _pool_views(store: Tree, gi: int, fmt: str, slot_layout: bool) -> Tree:
         if n == "k_planes":  # (NBITS, B, Hk, S, D/8) -> (NBITS, B*S, Hk, D/8)
             nb, B, Hk, S, Dp = a.shape
             out[n] = a.transpose(0, 1, 3, 2, 4).reshape(nb, B * S, Hk, Dp)
+        elif kvc.token_major(store, n):  # (B, S, Hk, D) -> (B*S, Hk, D)
+            B, S, Hk, D = a.shape
+            out[n] = a.reshape(B * S, Hk, D)
         elif a.ndim == 4:  # (B, Hk, S, D) -> (B*S, Hk, D)
             B, Hk, S, D = a.shape
             out[n] = a.transpose(0, 2, 1, 3).reshape(B * S, Hk, D)

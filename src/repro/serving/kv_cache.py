@@ -25,12 +25,14 @@ Two physical layouts for the *global* stacks (``CacheLayout.layout``):
           positions to pool rows.  Writes translate logical → physical with
           the same OOB-scatter-drop convention (unmapped page or padded
           lane => dropped); reads gather the slot's logical row back into
-          the exact heads-major view the slot layout serves, so attention
-          consumes bit-identical values (verified by the serving fuzz
-          oracle).  Page lifecycle (free lists, refcounts, prefix reuse)
-          is host-side: :mod:`repro.serving.paging`.  Local ring stacks,
-          mamba state, and cross memory stay slot-major — rings are
-          already fixed-width per-slot pages by construction.
+          the heads-major view the jnp attends read of a slot stack
+          (:func:`slot_entry`), so attention consumes bit-identical values
+          (verified by the serving fuzz oracle).  The slot stacks' bf16/int8
+          K/V bodies share the pools' token-major row order (a slot row is
+          one ``S``-row page).  Page lifecycle (free lists, refcounts,
+          prefix reuse) is host-side: :mod:`repro.serving.paging`.  Local
+          ring stacks, mamba state, and cross memory stay slot-major —
+          rings are already fixed-width per-slot pages by construction.
 """
 
 from __future__ import annotations
@@ -139,18 +141,20 @@ def layout_for(cfg, batch: int, max_seq: int, kv_format: str = "int8",
 
 
 def _kv_stack(n_layers, B, S, Hk, Dh, kv_format, dtype):
-    # heads-major (B, Hk, S, D) layout: decode attention needs no transpose,
-    # so the int8->f32 dequant fuses into the QK/PV dots instead of
-    # materializing f32 copies of the cache (§Perf iteration A1)
+    # bf16/int8 k/v bodies are token-major (B, S, Hk, D), the paged pools'
+    # row order: one decode token's (Hk, D) row is one contiguous tile, so
+    # the step programs scatter it in place instead of relaying the whole
+    # stack around the write.  Scales stay lane-dense (B, Hk, S) rows; the
+    # bgpp store stays heads-major throughout (see token_major)
     p: Tree = {}
     if n_layers == 0:
         return p
     if kv_format == "bf16":
-        p["k"] = jnp.zeros((n_layers, B, Hk, S, Dh), dtype)
-        p["v"] = jnp.zeros((n_layers, B, Hk, S, Dh), dtype)
+        p["k"] = jnp.zeros((n_layers, B, S, Hk, Dh), dtype)
+        p["v"] = jnp.zeros((n_layers, B, S, Hk, Dh), dtype)
     elif kv_format == "int8":
         for n in ("k", "v"):
-            p[n] = jnp.zeros((n_layers, B, Hk, S, Dh), jnp.int8)
+            p[n] = jnp.zeros((n_layers, B, S, Hk, Dh), jnp.int8)
             p[f"{n}_scale"] = jnp.zeros((n_layers, B, Hk, S), jnp.float32)
     elif kv_format == "bgpp":
         assert Dh % 8 == 0
@@ -167,8 +171,8 @@ def _kv_stack(n_layers, B, S, Hk, Dh, kv_format, dtype):
 def _kv_pool(n_layers, n_tok, Hk, Dh, kv_format, dtype):
     """Paged pool: token-major ``(L, n_tok, Hk, ...)`` per-layer stores with
     NO batch dim — ``n_tok = num_pages * page_size`` physical rows shared by
-    every slot through the page table.  Token-major (vs the slot layout's
-    heads-major) lets page gathers/scatters address one contiguous row
+    every slot through the page table.  Token-major (the slot stacks' K/V
+    body order too) lets page gathers/scatters address one contiguous row
     axis; reads restore the heads-major view (:func:`paged_entry`)."""
     p: Tree = {}
     if n_layers == 0:
@@ -220,12 +224,12 @@ def _kv_pool_specs(kv_format):
 
 def _kv_stack_specs(kv_format):
     if kv_format == "bf16":
-        ax = (sh.LAYERS, sh.BATCH, sh.KV_HEADS, sh.SEQ, None)
+        ax = (sh.LAYERS, sh.BATCH, sh.SEQ, sh.KV_HEADS, None)
         return {"k": ax, "v": ax}
     if kv_format == "int8":
         s = {}
         for n in ("k", "v"):
-            s[n] = (sh.LAYERS, sh.BATCH, sh.KV_HEADS, sh.SEQ, None)
+            s[n] = (sh.LAYERS, sh.BATCH, sh.SEQ, sh.KV_HEADS, None)
             s[f"{n}_scale"] = (sh.LAYERS, sh.BATCH, sh.KV_HEADS, sh.SEQ)
         return s
     if kv_format == "bgpp":
@@ -395,6 +399,71 @@ def bitplanes_to_k(planes: jax.Array, sign: jax.Array) -> jax.Array:
 
 
 # --------------------------------------------------------------------------
+# slot-stack addressing — token-major bodies, heads-major scales
+# --------------------------------------------------------------------------
+
+
+def token_major(store: Tree, name: str) -> bool:
+    """Whether leaf ``name`` of a slot stack is token-major
+    ``(L, B, S, Hk, D)``: the bf16/int8 ``k``/``v`` bodies.  Their scales
+    are lane-dense ``(L, B, Hk, S)`` rows, and a bgpp store (``k_planes``
+    present) stays heads-major in every leaf — its plane layout is the one
+    the bgpp decode reads."""
+    return name in ("k", "v") and "k_planes" not in store
+
+
+def _seq_index(store: Tree, name: str, idx, b, t) -> tuple:
+    """Index of seq positions ``t`` of batch row(s) ``b`` in layer(s)
+    ``idx`` of slot-stack leaf ``name`` (not ``k_planes``).  With ``b`` and
+    ``t`` both arrays, or ``b`` a slice, both forms select the same
+    ``(..., Hk[, D])`` target shape (advanced dims first)."""
+    if token_major(store, name):
+        return (idx, b, t)
+    return (idx, b, slice(None), t)
+
+
+def slot_entry(store: Tree, idx, slot=None) -> Tree:
+    """Layer ``idx`` of a slot stack as the heads-major entry the engine's
+    jnp attends read: ``(B, Hk, S, ...)`` every leaf (``k_planes``
+    ``(NBITS, B, Hk, S, D/8)``); ``slot`` (traced ok) keeps one batch row as
+    a batch of one; ring ``abs_pos`` is left out.
+
+    Token-major bodies are swapped at the read and the swap pinned as a
+    materialization point, as :func:`paged_entry`'s gathered view is in the
+    engine: a transpose left to fuse into the attend's dots changes their
+    float reduction order on some backends, and slot and paged attends
+    would no longer agree bit for bit.  The view is one layer's rows (one
+    slot's in a chunk); the whole stack is never relaid."""
+    out: Tree = {}
+    for n, a in store.items():
+        if n == "abs_pos":
+            continue
+        # one index op per leaf: a layer slice taken first would be copied
+        # whole (every slot) before the slot is picked out of it
+        if slot is None:
+            a = a[idx]
+        elif n == "k_planes":
+            a = a[idx, :, slot][:, None]
+        else:
+            a = a[idx, slot][None]
+        if token_major(store, n):
+            a = jax.lax.optimization_barrier(jnp.swapaxes(a, 1, 2))
+        out[n] = a
+    return out
+
+
+def _kv_rows(store: Tree, k: jax.Array, v: jax.Array) -> Tree:
+    """The leaves a bf16 or int8 store keeps for K/V rows ``(..., Hk, Dh)``:
+    values cast to the store's dtype, or int8 values ``(..., Hk, Dh)`` with
+    f32 scales ``(..., Hk)``."""
+    if "k_scale" in store:
+        kq, ks = quantize_kv(k)
+        vq, vs = quantize_kv(v)
+        return {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
+    return {"k": k.astype(store["k"].dtype), "v": v.astype(store["v"].dtype)}
+
+
+# --------------------------------------------------------------------------
 # paged addressing — logical position <-> physical pool row
 # --------------------------------------------------------------------------
 #
@@ -439,11 +508,11 @@ def _phys_write(page_table: jax.Array, tpos: jax.Array, page_size: int,
 
 
 def paged_entry(store: Tree, idx, phys: jax.Array) -> Tree:
-    """Gather layer ``idx`` of a paged pool back into the slot layout's
-    heads-major view: phys ``(B, S)`` -> entries ``(B, Hk, S, ...)`` (and
-    ``(NBITS, B, Hk, S, D/8)`` for bgpp planes).  The gathered values are
-    exactly the dense row's values, which is what keeps paged attention
-    bit-identical to the slot layout."""
+    """Gather layer ``idx`` of a paged pool into the heads-major entry
+    :func:`slot_entry` gives of a slot stack: phys ``(B, S)`` -> entries
+    ``(B, Hk, S, ...)`` (and ``(NBITS, B, Hk, S, D/8)`` for bgpp planes).
+    The gathered values are exactly the dense row's values, which is what
+    keeps paged attention bit-identical to the slot layout."""
     out: Tree = {}
     for n, a in store.items():
         if n == "k_planes":
@@ -593,8 +662,8 @@ def zero_token_range(store: Tree, tpos: jax.Array, *, page_table=None,
     for n, a in store.items():
         if n == "k_planes":  # (L, NBITS, B, Hk, S, D/8)
             store[n] = a.at[:, :, bidx, :, safe].set(0)
-        else:  # (L, B, Hk, S, ...)
-            store[n] = a.at[:, bidx, :, safe].set(0)
+        else:
+            store[n] = a.at[_seq_index(store, n, slice(None), bidx, safe)].set(0)
     return store
 
 
@@ -848,38 +917,20 @@ def write_token(store: Tree, idx: int, k: jax.Array, v: jax.Array,
         vq, vs = quantize_kv(v)
         store["v"] = store["v"].at[idx, bidx, :, tpos].set(vq[:, 0])
         store["v_scale"] = store["v_scale"].at[idx, bidx, :, tpos].set(vs[:, 0])
-    elif "k_scale" in store:  # int8
-        kq, ks = quantize_kv(k)
-        vq, vs = quantize_kv(v)
-        store["k"] = store["k"].at[idx, bidx, :, tpos].set(kq[:, 0])
-        store["v"] = store["v"].at[idx, bidx, :, tpos].set(vq[:, 0])
-        store["k_scale"] = store["k_scale"].at[idx, bidx, :, tpos].set(ks[:, 0])
-        store["v_scale"] = store["v_scale"].at[idx, bidx, :, tpos].set(vs[:, 0])
-    else:  # bf16
-        store["k"] = store["k"].at[idx, bidx, :, tpos].set(
-            k[:, 0].astype(store["k"].dtype))
-        store["v"] = store["v"].at[idx, bidx, :, tpos].set(
-            v[:, 0].astype(store["v"].dtype))
+    else:  # int8 / bf16: one (Hk, D) row per slot, (Hk,) scales
+        for n, x in _kv_rows(store, k, v).items():
+            store[n] = store[n].at[_seq_index(store, n, idx, bidx, tpos)].set(
+                x[:, 0])
     return store
 
 
 def _scatter_chunk_kv(store: Tree, idx: int, slot, tpos, k, v) -> Tree:
     """Quantize-and-scatter one chunk's K/V rows (int8 or bf16 stores) into
     seq indices ``tpos`` of batch row ``slot`` — the shared tail of both
-    chunked write paths (``.at[idx, slot, :, tpos]`` selects ``(S, Hk, ...)``
-    advanced-dims-first; OOB lanes drop)."""
-    if "k_scale" in store:
-        kq, ks = quantize_kv(k)
-        vq, vs = quantize_kv(v)
-        store["k"] = store["k"].at[idx, slot, :, tpos].set(kq[0])
-        store["v"] = store["v"].at[idx, slot, :, tpos].set(vq[0])
-        store["k_scale"] = store["k_scale"].at[idx, slot, :, tpos].set(ks[0])
-        store["v_scale"] = store["v_scale"].at[idx, slot, :, tpos].set(vs[0])
-    else:
-        store["k"] = store["k"].at[idx, slot, :, tpos].set(
-            k[0].astype(store["k"].dtype))
-        store["v"] = store["v"].at[idx, slot, :, tpos].set(
-            v[0].astype(store["v"].dtype))
+    chunked write paths (each leaf's index selects ``(S, Hk, ...)``; OOB
+    lanes drop)."""
+    for n, x in _kv_rows(store, k, v).items():
+        store[n] = store[n].at[_seq_index(store, n, idx, slot, tpos)].set(x[0])
     return store
 
 
@@ -897,16 +948,9 @@ def _scatter_paged_kv(store: Tree, idx, phys, k, v) -> Tree:
         vq, vs = quantize_kv(v)
         store["v"] = store["v"].at[idx, phys].set(vq)
         store["v_scale"] = store["v_scale"].at[idx, phys].set(vs)
-    elif "k_scale" in store:
-        kq, ks = quantize_kv(k)
-        vq, vs = quantize_kv(v)
-        store["k"] = store["k"].at[idx, phys].set(kq)
-        store["v"] = store["v"].at[idx, phys].set(vq)
-        store["k_scale"] = store["k_scale"].at[idx, phys].set(ks)
-        store["v_scale"] = store["v_scale"].at[idx, phys].set(vs)
     else:
-        store["k"] = store["k"].at[idx, phys].set(k.astype(store["k"].dtype))
-        store["v"] = store["v"].at[idx, phys].set(v.astype(store["v"].dtype))
+        for n, x in _kv_rows(store, k, v).items():
+            store[n] = store[n].at[idx, phys].set(x)
     return store
 
 
@@ -972,11 +1016,12 @@ def write_prefill(store: Tree, idx: int, k: jax.Array, v: jax.Array,
         return _scatter_chunk_kv(store, idx, slot, tpos, k, v)
     if slot is None:
         bsel: Any = slice(None)
-        tr = lambda a: jnp.swapaxes(a, 1, 2)  # (B,S,Hk,...) -> (B,Hk,S,...)
+        lead = lambda a: a
     else:
         assert k.shape[0] == 1, "slot admission writes one prompt at a time"
         bsel = slot
-        tr = lambda a: jnp.swapaxes(a, 1, 2)[0]  # -> (Hk,S,...)
+        lead = lambda a: a[0]
+    tr = lambda a: lead(jnp.swapaxes(a, 1, 2))  # (B,S,Hk,...) -> (B,Hk,S,...)
     if "k_planes" in store:
         kq, ks = quantize_kv(k)
         planes, sign = k_to_bitplanes(kq)  # (NBITS,B,S,Hk,D/8)
@@ -988,18 +1033,12 @@ def write_prefill(store: Tree, idx: int, k: jax.Array, v: jax.Array,
         vq, vs = quantize_kv(v)
         store["v"] = store["v"].at[idx, bsel, :, :S].set(tr(vq))
         store["v_scale"] = store["v_scale"].at[idx, bsel, :, :S].set(tr(vs))
-    elif "k_scale" in store:
-        kq, ks = quantize_kv(k)
-        vq, vs = quantize_kv(v)
-        store["k"] = store["k"].at[idx, bsel, :, :S].set(tr(kq))
-        store["v"] = store["v"].at[idx, bsel, :, :S].set(tr(vq))
-        store["k_scale"] = store["k_scale"].at[idx, bsel, :, :S].set(tr(ks))
-        store["v_scale"] = store["v_scale"].at[idx, bsel, :, :S].set(tr(vs))
-    else:
-        store["k"] = store["k"].at[idx, bsel, :, :S].set(
-            tr(k).astype(store["k"].dtype))
-        store["v"] = store["v"].at[idx, bsel, :, :S].set(
-            tr(v).astype(store["v"].dtype))
+        return store
+    for n, x in _kv_rows(store, k, v).items():
+        if token_major(store, n):
+            store[n] = store[n].at[idx, bsel, :S].set(lead(x))
+        else:
+            store[n] = store[n].at[idx, bsel, :, :S].set(tr(x))
     return store
 
 
@@ -1034,25 +1073,15 @@ def write_prefill_local(store: Tree, idx: int, k: jax.Array, v: jax.Array,
     k, v = k[:, -take:], v[:, -take:]
     if slot is None:
         bsel: Any = slice(None)
-        # .at[idx, :, :, slots] targets (take, B, Hk, D) — advanced dim first
+        # the ring index targets (take, B, Hk, ...) — advanced dims first
         tr = lambda a: jnp.swapaxes(a, 0, 1)
     else:
         assert B == 1
         bsel = slot
-        # .at[idx, slot, :, slots] targets (take, Hk, D)
+        # .. and (take, Hk, ...) for one slot
         tr = lambda a: a[0]
-    if "k_scale" in store:
-        kq, ks = quantize_kv(k)
-        vq, vs = quantize_kv(v)
-        store["k"] = store["k"].at[idx, bsel, :, slots].set(tr(kq))
-        store["v"] = store["v"].at[idx, bsel, :, slots].set(tr(vq))
-        store["k_scale"] = store["k_scale"].at[idx, bsel, :, slots].set(tr(ks))
-        store["v_scale"] = store["v_scale"].at[idx, bsel, :, slots].set(tr(vs))
-    else:
-        store["k"] = store["k"].at[idx, bsel, :, slots].set(
-            tr(k).astype(store["k"].dtype))
-        store["v"] = store["v"].at[idx, bsel, :, slots].set(
-            tr(v).astype(store["v"].dtype))
+    for n, x in _kv_rows(store, k, v).items():
+        store[n] = store[n].at[_seq_index(store, n, idx, bsel, slots)].set(tr(x))
     if slot is None:
         store["abs_pos"] = store["abs_pos"].at[idx, :, slots].set(
             jnp.broadcast_to(pos_abs, (B, take)).T)

@@ -225,7 +225,7 @@ class Scheduler:
             self.serve_step = shared_fns["serve_step"]
             self.chunked = shared_fns.get("chunked")
         else:
-            self.serve_step = jax.jit(engine.make_serve_step(cfg, layout, rules))
+            self.serve_step = engine.jit_serve_step(cfg, layout, rules)
             self.chunked = None
         if admission == "chunked" and self.chunked is None:
             # shared_fns came from an eager scheduler (or none given)

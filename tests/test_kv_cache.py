@@ -99,10 +99,11 @@ def test_paged_writes_match_slot_layout(fmt):
     phys = kvc.phys_table(pt, ps, S)
     for gi in range(3):
         view = kvc.paged_entry(paged, gi, phys)
+        entry = kvc.slot_entry(dense, gi)
         for n in dense:
-            # dense layer slice and paged gather view share one shape:
+            # dense layer entry and paged gather view share one shape:
             # (B, Hk, S, ...) — and must share every value
-            assert np.array_equal(np.asarray(dense[n][gi]),
+            assert np.array_equal(np.asarray(entry[n]),
                                   np.asarray(view[n])), (fmt, gi, n)
 
 

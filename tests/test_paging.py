@@ -327,6 +327,54 @@ def test_scheduler_eviction_zeroes_freed_pages(fmt):
 
 
 # --------------------------------------------------------------------------
+# a slot row is a paged row: the two layouts store one token-major order
+# --------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("fmt", ["bf16", "int8"])
+def test_slot_rows_equal_paged_pool_rows(fmt):
+    """Admit the same prompts into a slot scheduler and a paged one and
+    decode a few steps in both: slot row ``[gi, b, s]`` of ``k``/``v``
+    (token-major ``(Hk, D)``) must equal the pool row the paged table
+    maps ``(b, s)`` to, and the int8 scales ``[gi, b, :, s]`` the pool's
+    ``(Hk,)`` scale row, at every written position of every slot."""
+    cfg = get_config("phi4-mini-3.8b", smoke=True)
+    from repro.models import model_zoo
+    params, _ = model_zoo.init(jax.random.key(0), cfg)
+    B, S, ps = 3, 32, 8
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(0, cfg.vocab_size, (n,)).astype(np.int32)
+               for n in (5, 13, 9)]
+    caches = {}
+    for kind in ("slot", "paged"):
+        layout = kvc.layout_for(cfg, B, S, kv_format=fmt, layout=kind,
+                                page_size=ps)
+        sched = Scheduler(params, cfg, layout, chunk_budget=8)
+        for i, p in enumerate(prompts):
+            sched.submit(Request(rid=i, prompt=p, max_new_tokens=12))
+        for _ in range(6):
+            sched.step()
+        assert not sched.finished and all(
+            s.state.name == "DECODING" for s in sched.slots)
+        caches[kind] = sched.cache
+    table = sched.pager.table  # the paged scheduler ran last
+    slot, pool = caches["slot"]["global"], caches["paged"]["global"]
+    pos = np.asarray(caches["slot"]["pos"])
+    np.testing.assert_array_equal(pos, np.asarray(caches["paged"]["pos"]))
+    for b in range(B):
+        s = np.arange(pos[b])  # every position a step has written
+        rows = table[b, s // ps] * ps + s % ps
+        assert np.all(table[b, s // ps] >= 0)
+        for n in slot:
+            got, want = np.asarray(slot[n]), np.asarray(pool[n])[:, rows]
+            if kvc.token_major(slot, n):  # (L, B, S, Hk, D)
+                got = got[:, b, :pos[b]]
+            else:  # scales (L, B, Hk, S) against pool rows (L, n, Hk)
+                got = np.swapaxes(got[:, b, :, :pos[b]], 1, 2)
+            np.testing.assert_array_equal(got, want, err_msg=f"{n} slot {b}")
+
+
+# --------------------------------------------------------------------------
 # sharing legitimacy: only identical page-aligned prefixes may share
 # --------------------------------------------------------------------------
 

@@ -210,11 +210,13 @@ class TestZeroTokenRange:
                                    max_seq=layout.max_seq)
         for n, a in out.items():
             arr = np.asarray(a)
-            # slot stacks: (L, B, Hk, S, ...) with k_planes carrying an
-            # extra leading NBITS dim -> batch at bdim, tokens at bdim + 2
+            # slot stacks: token-major bodies (L, B, S, Hk, D), heads-major
+            # scales and bgpp leaves (L, B, Hk, S, ...), k_planes carrying
+            # an extra leading NBITS dim -> batch at bdim, tokens after it
             bdim = 2 if n == "k_planes" else 1
+            tdim = bdim + (1 if kvc.token_major(out, n) else 2)
             tok = np.moveaxis(np.moveaxis(arr, bdim, 0),
-                              bdim + 2, 1)  # (B, S, ...)
+                              tdim, 1)  # (B, S, ...)
             assert np.all(tok[0, [5, 9]] == 0), f"{n}: target rows survive"
             keep = np.delete(tok[0], [5, 9], axis=0)
             assert np.all(keep == 3), f"{n}: slot 0 overreach"

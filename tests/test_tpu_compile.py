@@ -1,14 +1,22 @@
-"""The served decode kernels compile for a TPU v5e — no chip needed.
+"""The served decode kernels and step programs compile for a TPU v5e — no
+chip needed.
 
-Each test lowers one Pallas kernel at phi4-mini-3.8b's published attention
-widths (8 kv heads, GQA group 3, head_dim 128) and the pool sizes
+Each kernel test lowers one Pallas kernel at phi4-mini-3.8b's published
+attention widths (8 kv heads, GQA group 3, head_dim 128) and the pool sizes
 ``chip_smoke.py`` serves (8 slots, max_seq 1024) and compiles it for a
 described ``v5e:2x2`` with the TPU compiler installed here.  That is what
 interpret mode cannot show: Mosaic refuses block shapes off the (8, 128)
-tiling and kernels that overrun the scoped VMEM.  Nothing runs.
+tiling and kernels that overrun the scoped VMEM.  The step tests compile the
+int8 slot-layout decode step and a prefill chunk at the same widths (a few
+layers) and read what the compiler made of the cache: no relayout of a whole
+K/V stack, and a decode step that updates its donated cache in place.
+Nothing runs.
 """
 
+import dataclasses
+import math
 import os
+import re
 
 import pytest
 
@@ -19,7 +27,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels.bgpp_paged_attend.kernel import bgpp_paged_attend_pallas
 from repro.kernels.paged_flash_decode.kernel import paged_flash_decode_pallas
-from repro.serving import kv_cache as kvc
+from repro.models import model_zoo
+from repro.serving import engine, kv_cache as kvc
 
 SLOTS, MAX_SEQ = 8, 1024  # chip_smoke.py's serving shape
 
@@ -122,3 +131,78 @@ def test_bgpp_paged_attend_compiles(one_chip, layout_kind, page_size):
         ),
         *args,
     )
+
+
+# --------------------------------------------------------------------------
+# whole step programs: the int8 slot cache stays in place
+# --------------------------------------------------------------------------
+
+STEP_LAYERS = 2  # every stack is still (layers, slots, ...); compiles fast
+CHUNK = 256  # the benchmark cell's chunk budget, its largest bucket
+
+
+@pytest.fixture(scope="module")
+def step_programs(one_chip):
+    """The decode step as the scheduler jits it and one prefill-chunk
+    bucket as ``ChunkedPrefill`` jits it, compiled for one described v5e
+    with the compiled decode kernel, plus the cache's shapes."""
+    cfg = dataclasses.replace(_cfg(), num_layers=STEP_LAYERS)
+    lay = kvc.layout_for(cfg, SLOTS, MAX_SEQ, kv_format="int8")
+    place = lambda tree: jax.tree.map(
+        lambda s: one_chip(s.shape, s.dtype), tree)
+    params = place(jax.eval_shape(
+        lambda k: model_zoo.init_params(k, cfg), jax.random.key(0)))
+    cache = place(jax.eval_shape(lambda: kvc.init_cache_arrays(cfg, lay)))
+    i32 = lambda shape: one_chip(shape, jnp.int32)
+    with pytest.MonkeyPatch.context() as mp:
+        # the program picks its decode attend and kernel dispatch from the
+        # default backend: compile the chip's program, not the CPU's
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        step = engine.jit_serve_step(cfg, lay).lower(
+            params, cache, i32((SLOTS, 1))).compile()
+        chunk = engine.ChunkedPrefill(cfg, lay, buckets=(CHUNK,))._chunk
+        chunk = chunk.lower(params, cache, i32((1, CHUNK)), i32(()), i32(()),
+                            i32(())).compile()
+    assert "tpu_custom_call" in step.as_text()
+    return {"serve_step": step, "prefill_chunk": chunk}, cache
+
+
+def _entry_ops(compiled, opcode=r"[\w-]+"):
+    """``(dtype, element count)`` of every array the entry computation's
+    ``opcode`` ops make (``copy`` is a relayout; async memory-space moves
+    are ``copy-start``/``-done``)."""
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    return [(dt, math.prod(int(d) for d in dims.split(",") if d))
+            for dt, dims in re.findall(
+                rf"= (\w+)\[([\d,]*)\]\{{[^}}]*\}} {opcode}\(", entry)]
+
+
+@pytest.mark.parametrize("program", ["serve_step", "prefill_chunk"])
+def test_step_program_relays_no_kv_stack(step_programs, program):
+    """A heads-major stack written one token row at a time is relaid whole
+    on the way in and out of each step program (four 1.34 GB copies a step
+    at the benchmark's size); token-major stacks take the row in place."""
+    progs, cache = step_programs
+    stack = {a.size for n, a in cache["global"].items() if n in ("k", "v")}
+    whole = [c for c in _entry_ops(progs[program], "copy")
+             if c[0] == "s8" and c[1] in stack]
+    assert not whole, f"{program} copies whole K/V stacks: {whole}"
+
+
+def test_prefill_chunk_reads_one_slot(step_programs):
+    """The chunk attends one slot's rows: no op makes a whole layer of the
+    K/V stack (every slot's rows) on the way to that slot's 2 MB."""
+    progs, cache = step_programs
+    layer = {a.size // STEP_LAYERS
+             for n, a in cache["global"].items() if n in ("k", "v")}
+    whole = [c for c in _entry_ops(progs["prefill_chunk"])
+             if c[0] == "s8" and c[1] in layer]
+    assert not whole, f"prefill_chunk makes whole K/V layers: {whole}"
+
+
+def test_serve_step_donates_cache(step_programs):
+    progs, cache = step_programs
+    alias = progs["serve_step"].memory_analysis().alias_size_in_bytes
+    assert alias >= kvc.cache_bytes(cache), (alias, kvc.cache_bytes(cache))
