@@ -52,9 +52,10 @@ pools heads-parallel on ``model``, slots on ``data``; needs data*model
 visible devices) and the emitted rows gain per-device and interconnect
 kv-bytes columns.  With or without the flag, each format's baseline entry
 carries ``kv_read_mesh`` — the static per-mesh decode-read pricing for
-1x1 / 2x1 / 1x4 / 2x4 (total, per-device share, attend all-gather + paged
-write-broadcast interconnect) — plus a ``sharded_smoke`` section pinning
-the single-device occupancy the CI meshed launcher smoke is gated on.
+1x1 / 2x1 / 1x4 / 2x4 (total, per-device share, tensor-parallel
+all-reduces + paged write-broadcast interconnect) — plus a
+``sharded_smoke`` section pinning the single-device occupancy the CI
+meshed launcher smoke is gated on.
 
   paged    — the chunked scheduler on the paged KV layout (pooled pages +
              page table + hash-based prefix reuse), driven by a trace whose
@@ -131,13 +132,15 @@ ITL_RATIO_FACTOR = 4.0  # chunked/eager itl_p95 ratio growth allowed
 MESH_POINTS = ((1, 1), (2, 1), (1, 4), (2, 4))
 
 
-def mesh_kv_entries(layout, cfg):
+def mesh_kv_entries(layout, cfg, params):
     """Per-mesh decode-read breakdown: total, per-device share, and the
-    interconnect bytes (attend all-gather + paged write broadcast) a sharded
-    serve_step moves per decode step."""
+    interconnect bytes (tensor-parallel all-reduces + paged write
+    broadcast) a sharded serve_step moves per decode step."""
     out = {}
+    specs = model_zoo.param_specs(cfg)
     for d, m in MESH_POINTS:
-        r = kvc.decode_read_bytes(layout, cfg, (d, m))
+        r = kvc.decode_read_bytes(
+            layout, cfg, (d, m), shd.projection_shards(params, specs, (d, m)))
         out[f"{d}x{m}"] = {
             "decode_bytes_per_step": round(r["total"]),
             "per_device_bytes_per_step": round(r["per_device"]["total"]),
@@ -357,7 +360,7 @@ def main():
     for fmt in formats:
         layout = kvc.layout_for(cfg, args.slots, args.max_seq, kv_format=fmt)
         entry = {"decode_kernel": dk_mode, "weight_format": wf_mode,
-                 "kv_read_mesh": mesh_kv_entries(layout, cfg)}
+                 "kv_read_mesh": mesh_kv_entries(layout, cfg, params)}
         shared = None
         chunk_sink = {}
         runtimes = ["chunked", "eager"] + ([] if args.quick else ["lockstep"])
@@ -489,7 +492,8 @@ def main():
                 params, cfg, layout_p, preqs, "chunked", args.chunk_budget,
                 rules=rules,
             )
-            entry["paged"]["kv_read_mesh"] = mesh_kv_entries(layout_p, cfg)
+            entry["paged"]["kv_read_mesh"] = mesh_kv_entries(layout_p, cfg,
+                                                             params)
             r = entry["paged"]
             us = 1e6 / r["tokens_per_s"] if r["tokens_per_s"] else 0.0
             emit(f"serving_{fmt}_paged", us,
@@ -631,7 +635,7 @@ def main():
     for wf in WEIGHT_FORMATS:
         _, plan = swt.prepare_serve_params(
             params, apply_weight_format_override(cfg, wf), wlayout, wf)
-        wrd = plan.decode_read_bytes(wlayout, cfg)
+        wrd = plan.decode_read_bytes()
         weight_entry[wf] = {
             "decode_bytes_per_step": round(wrd["total"]),
             "modeled_bytes_per_step": round(wrd["modeled"]),
@@ -683,7 +687,7 @@ def main():
             "slots": 4, "requests": 4, "max_new": 8, "max_seq": 128,
             "chunk_budget": 16, "arrival_rate": 2.0, "seed": args.seed,
             "mean_occupancy": smoke["mean_occupancy"],
-            "kv_read_mesh": mesh_kv_entries(slayout, scfg),
+            "kv_read_mesh": mesh_kv_entries(slayout, scfg, sparams),
         }
         sm = results["sharded_smoke"]["kv_read_mesh"]["2x4"]
         print(f"# sharded_smoke (deepseek-7b, 4 slots, bf16 slot): "

@@ -34,7 +34,9 @@ Checks, each of which ends the run with a non-zero exit code:
 heads, 13.8 GB in bf16) on a ``1x4`` (data, model) mesh with int8 KV, the
 kernel decode running under ``shard_map``, compared with
 ``model_zoo.forward`` on the same mesh (``INT8_TOL``), and checks that
-every device holds the weight bytes the serving placement assigns it.
+every device holds the weight bytes the serving placement assigns it: a
+quarter of every matrix (tensor parallel over ``"model"``), and the
+replicated norms whole.
 
 Compile seconds, requests and tokens finished and peak device memory are
 printed per phase.  The last line of standard output is one JSON object,
@@ -328,9 +330,12 @@ def four_chips(jax, serve, seed, clog):
         + ", ".join(f"{b / 2**30:.2f}" for b in held.values())
         + f" GiB; the serving placement assigns {expect / 2**30:.2f} GiB "
         f"({expect / total:.3f} of the total) to each")
-    if any(b != expect for b in held.values()) or expect >= total:
+    whole = sum(x.nbytes for x in leaves if x.sharding.is_fully_replicated)
+    if any(b != expect for b in held.values()) or \
+            expect != (total - whole) // 4 + whole:
         fail("weights are not split across the four devices as the "
-             "serving placement says")
+             "serving placement says: a quarter of every matrix, the "
+             "norms whole")
 
     args = serve_args(serve, ["--arch", "deepseek-7b", "--kv-format", "int8",
                               "--mesh", "1,4"], seed)

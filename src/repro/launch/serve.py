@@ -301,9 +301,11 @@ def run(args, params=None, *, record_logits: bool = False,
           f"({kv['kv_shards']} kv shards): "
           f"{kv['decode_bytes_per_device_per_step']/1e3:.1f} kB/device/step, "
           f"interconnect {kv['interconnect_bytes_per_step']/1e3:.2f} kB/step "
-          f"({kv['interconnect_bytes']/1e6:.2f} MB total: attend all-gather "
-          f"{kv['interconnect']['attend_allgather']/1e3:.2f} kB/step + paged "
-          f"write bcast {kv['interconnect']['paged_write_bcast']/1e3:.2f})")
+          f"({kv['interconnect_bytes']/1e6:.2f} MB total: all-reduces "
+          f"{kv['interconnect']['attn_out_allreduce']/1e3:.2f} kB/step after "
+          f"wo + {kv['interconnect']['mlp_out_allreduce']/1e3:.2f} after the "
+          f"MLP + paged write bcast "
+          f"{kv['interconnect']['paged_write_bcast']/1e3:.2f})")
     wr = stats["weight_read"]
     print(f"[serve] weight read ({wr['weight_format']}): "
           f"{wr['decode_bytes']/1e6:.2f} MB decode + "

@@ -122,7 +122,7 @@ def sinusoidal_positions(num_pos: int, dim: int) -> jax.Array:
 # --------------------------------------------------------------------------
 
 
-def wdot(x: jax.Array, w) -> jax.Array:
+def wdot(x: jax.Array, w, preferred_element_type=None) -> jax.Array:
     """``x @ w`` for a raw weight leaf OR a serve-time quantized record.
 
     ``repro.serving.weights.prepare_serve_params`` replaces projection
@@ -130,14 +130,18 @@ def wdot(x: jax.Array, w) -> jax.Array:
     ``weight_format`` is int8/bstc; this helper dequantizes the record to
     the dense reconstruction (the parity oracle) and contracts in the
     activation dtype.  Raw arrays take the plain matmul — the bf16 default
-    path is byte-for-byte the old ``x @ w``.
+    path is byte-for-byte the old ``x @ w``.  ``preferred_element_type``
+    sets the product's dtype (the tensor-parallel serving path keeps its
+    partial sums in float32).
     """
     if isinstance(w, dict) and "q" in w:
         dq = w["q"].astype(jnp.float32) * w["scale"][..., None, :].astype(
             jnp.float32
         )
-        return x @ dq.astype(x.dtype)
-    return x @ w
+        w = dq.astype(x.dtype)
+    if preferred_element_type is None:
+        return x @ w
+    return jnp.matmul(x, w, preferred_element_type=preferred_element_type)
 
 
 # --------------------------------------------------------------------------
@@ -186,13 +190,16 @@ def mlp_init(key, d_model: int, d_ff: int, activation: str, dtype):
     return params, mlp_specs(activation)
 
 
-def mlp_apply(params, x: jax.Array, activation: str) -> jax.Array:
+def mlp_apply(params, x: jax.Array, activation: str, down=wdot) -> jax.Array:
+    """Dense MLP; ``down(h, w)`` contracts the hidden activations with the
+    down projection (the tensor-parallel serving path passes its
+    row-parallel product and all-reduce)."""
     if activation in ("swiglu", "geglu"):
         gate = wdot(x, params["gate"])
         up = wdot(x, params["up"])
-        return wdot(glu_act(activation, gate) * up, params["down"])
+        return down(glu_act(activation, gate) * up, params["down"])
     h = jax.nn.gelu(wdot(x, params["up"]) + params["up_b"], approximate=True)
-    return wdot(h, params["down"]) + params["down_b"]
+    return down(h, params["down"]) + params["down_b"]
 
 
 # --------------------------------------------------------------------------

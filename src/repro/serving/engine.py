@@ -19,6 +19,7 @@ caches), indexing the stacked parameter pytrees with static layer ids.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -29,10 +30,33 @@ import numpy as np
 from repro.core import attention, bgpp as bgpp_mod, bitslice
 from repro.distributed import sharding as sh
 from repro.models import layers, mamba2, moe, transformer
-from repro.serving import kernel_decode, kv_cache as kvc, weights as swt
+from repro.serving import kernel_decode, kv_cache as kvc, sharded as shd
+from repro.serving import weights as swt
 
 Tree = Dict[str, Any]
 NEG_INF = attention.NEG_INF
+
+
+def _row_parallel(x, w, rules, axis=sh.HEADS, scope="tp.attn_out"):
+    """``x @ w`` for a row-parallel projection: ``x`` stays sharded over
+    ``"model"`` on its last (logical ``axis``) axis, as ``w`` is on its
+    rows (``serving.sharded``), so every device multiplies its own slice,
+    and one all-reduce in :data:`kv_cache.TP_SUM_DTYPE` (named ``scope`` in
+    the device trace) sums the partial outputs, replicated over
+    ``"model"``.  The sum is rounded once to the dtype ``x @ w`` has, as
+    one device's dot rounds its accumulator.  On a ``"model"`` axis of size
+    1 nothing is summed across devices: the product is that one dot, in
+    ``x @ w``'s dtype (a float32 output there would only write a prefill
+    chunk's bf16 rows at twice the bytes)."""
+    summed = shd.mesh_shape(rules)[1] > 1
+    # a quantized record dequantizes to x's dtype (layers.wdot)
+    dtype = x.dtype if isinstance(w, dict) else jnp.result_type(x, w)
+    x = sh.constrain(x, rules, (sh.BATCH, None, axis))
+    with jax.named_scope(scope):
+        y = layers.wdot(x, w, preferred_element_type=(
+            kvc.TP_SUM_DTYPE if summed else None))
+        y = sh.constrain(y, rules, (sh.BATCH, None, None))
+    return y.astype(dtype)
 
 
 # --------------------------------------------------------------------------
@@ -492,13 +516,8 @@ def _attn_decode_layer(p, cfg, layout, cache, x, pos, layer_idx, theta, rules,
                 else:
                     out = _decode_attend(q[:, 0], entry, valid, cfg, fmt)
 
-    # the attend reduction's ONLY collective: all-gather the per-head f32
-    # outputs across "model" before the replicated wo contraction.  Pure
-    # data movement (no psum splits a float reduction), so sharded decode
-    # stays bit-exact vs single-device — this is the priced interconnect
-    # term in kv_cache._interconnect_decode.
-    out = sh.constrain(out.reshape(B, 1, -1), rules, (sh.BATCH, None, None))
-    out = layers.wdot(out, p["attn"]["wo"])
+    # the head outputs stay on their devices into the row-parallel wo
+    out = _row_parallel(out.reshape(B, 1, -1), p["attn"]["wo"], rules)
     if cfg.post_norms and "post_attn_norm" in p:
         out = layers.apply_norm(out, p["post_attn_norm"], cfg.norm)
     return out, cache
@@ -517,7 +536,12 @@ def _ffn_decode_layer(p, cfg, x, rules=None):
             rules=rules,
         )
     else:
-        out = layers.mlp_apply(p["mlp"], h, cfg.activation)
+        # gate/up column-parallel over FF, down row-parallel
+        out = layers.mlp_apply(
+            p["mlp"], h, cfg.activation,
+            down=functools.partial(_row_parallel, rules=rules, axis=sh.FF,
+                                   scope="tp.mlp_out"),
+        )
     if cfg.post_norms and "post_mlp_norm" in p:
         out = layers.apply_norm(out, p["post_mlp_norm"], cfg.norm)
     return out
@@ -946,11 +970,8 @@ def _attn_chunk_layer(p, cfg, layout, cache, x, slot, offset, length,
         else:
             out = _cache_attend(q, view, valid, cfg, fmt)
 
-    # all-gather the head outputs across "model" before the replicated wo
-    # (same bit-exact attend-reduction boundary as the decode layer)
-    out = sh.constrain(out.astype(x.dtype).reshape(B, C, -1), rules,
-                       (sh.BATCH, None, None))
-    out = out @ p["attn"]["wo"]
+    out = _row_parallel(out.astype(x.dtype).reshape(B, C, -1),
+                        p["attn"]["wo"], rules)
     if cfg.post_norms and "post_attn_norm" in p:
         out = layers.apply_norm(out, p["post_attn_norm"], cfg.norm)
     return out, cache

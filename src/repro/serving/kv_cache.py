@@ -724,32 +724,59 @@ def mesh_shard_factors(layout: CacheLayout, cfg, mesh_shape) -> Tuple[int, int]:
     return d_eff, m_eff
 
 
-def _interconnect_decode(layout: CacheLayout, cfg, d_eff: int,
-                         m_eff: int) -> Dict[str, float]:
+# dtype of the tensor-parallel all-reduces (engine._row_parallel): on a
+# "model" axis above 1 each device's partial sum of a row-parallel
+# projection (wo, the MLP's down) is kept in float32 and rounded to the
+# activation dtype once, after the sum, as one device's dot rounds its
+# float32 accumulator once.  Decode activations are float32 already; a
+# prefill chunk's bf16 (C, d_model) partials double in bytes.
+TP_SUM_DTYPE = jnp.float32
+
+
+def _tp_allreduce(rows: float, cfg, shards: Dict[str, Tuple[int, int]],
+                  groups: int) -> Dict[str, float]:
+    """Bytes the tensor-parallel all-reduces of one step move, over the
+    whole mesh: one after each row-parallel projection of every layer,
+    ``wo`` (``attn_out_allreduce``) and a dense MLP's ``down``
+    (``mlp_out_allreduce``), each of ``rows`` activation rows of d_model
+    :data:`TP_SUM_DTYPE` partial sums.  ``shards`` gives each projection's
+    (input-row, output-column) shard counts as the placement made them
+    (``repro.serving.sharded.projection_shards``).  A ring all-reduce over
+    ``r`` row shards sends ``2 * (r - 1) / r`` of the array from each of
+    them, so a group moves ``2 * (r - 1) * rows * d_model * itemsize``
+    bytes; ``groups`` groups (one per data replica that computes those
+    rows) each move as much.  A projection kept whole (``r == 1``: no
+    mesh, or the placement's divisibility fallback) sums nothing."""
+    per_row = cfg.d_model * jnp.dtype(TP_SUM_DTYPE).itemsize * groups
+    out = {}
+    for proj, key in (("wo", "attn_out_allreduce"),
+                      ("down", "mlp_out_allreduce")):
+        r = shards.get(proj, (1, 1))[0]
+        out[key] = 2.0 * (r - 1) * rows * per_row * cfg.num_layers
+    return out
+
+
+def _interconnect_decode(layout: CacheLayout, cfg, mesh_shape, d_eff: int,
+                         shards) -> Dict[str, float]:
     """Collective bytes ONE batched serve_step moves between devices.
 
-    Two collectives are priced — the only ones the bit-exact sharding
-    layout allows (contractions are never split, so there is no psum):
-
-    * ``attend_allgather`` — per attention layer, the f32 per-head attend
-      outputs ``(B, Hq, Dh)`` are all-gathered across ``"model"`` before
-      the (replicated) ``wo`` projection.  Each of the ``m_eff`` shards
-      sends its ``1/m_eff`` slice to the other ``m_eff - 1`` peers.
+    * ``attn_out_allreduce`` / ``mlp_out_allreduce`` — the two
+      tensor-parallel all-reduces of each layer (:func:`_tp_allreduce`)
+      over the step's ``B`` rows; a batch the ``"data"`` axis cannot split
+      is reduced by every data replica.
     * ``paged_write_bcast`` — paged pools have no batch axis, so they are
       replicated across ``"data"``; the B decode-token KV rows (computed
       batch-sharded) must reach every data replica of the pool.
     """
     B = layout.batch
-    ng, nl = len(layout.global_layers), len(layout.local_layers)
-    attend = (m_eff - 1) * B * cfg.num_heads * cfg.head_dim * 4.0 * (ng + nl)
-    paged_w = 0.0
+    out = _tp_allreduce(B, cfg, shards, int(mesh_shape[0]) // d_eff)
+    ng = len(layout.global_layers)
+    out["paged_write_bcast"] = 0.0
     if layout.layout == "paged" and ng:
-        paged_w = (d_eff - 1) * B * ng * _token_row_bytes(cfg, layout.kv_format)
-    return {
-        "attend_allgather": attend,
-        "paged_write_bcast": paged_w,
-        "total": attend + paged_w,
-    }
+        out["paged_write_bcast"] = ((d_eff - 1) * B * ng
+                                    * _token_row_bytes(cfg, layout.kv_format))
+    out["total"] = sum(out.values())
+    return out
 
 
 def bgpp_decode_plan(S: int, cfg) -> Tuple[int, int, Tuple[int, ...]]:
@@ -785,7 +812,9 @@ def bgpp_decode_plan(S: int, cfg) -> Tuple[int, int, Tuple[int, ...]]:
 
 
 def decode_read_bytes(layout: CacheLayout, cfg,
-                      mesh_shape: Tuple[int, int] = (1, 1)) -> Dict[str, Any]:
+                      mesh_shape: Tuple[int, int] = (1, 1),
+                      shards: Optional[Dict[str, Tuple[int, int]]] = None,
+                      ) -> Dict[str, Any]:
     """KV bytes ONE batched ``serve_step`` gathers, at its static shapes.
 
     All ``layout.batch`` rows and every cached layer are counted (the
@@ -803,7 +832,8 @@ def decode_read_bytes(layout: CacheLayout, cfg,
     ``"per_device"`` (the same counters divided by the effective shard
     count — reads are batch-sharded over ``"data"`` and head-sharded over
     ``"model"``, so each device gathers ``total / (d_eff * m_eff)`` bytes)
-    and ``"interconnect"`` (see :func:`_interconnect_decode`).  At 1×1
+    and ``"interconnect"`` (see :func:`_interconnect_decode`; ``shards``
+    are the projections' shard counts, :func:`_tp_allreduce`).  At 1×1
     per-device equals total and interconnect is zero.
     """
     B, S, W = layout.batch, layout.max_seq, layout.local_window
@@ -832,47 +862,46 @@ def decode_read_bytes(layout: CacheLayout, cfg,
     out["total"] = out["global"] + out["local"]
     out["bf16_equiv"] = (B * ng * S + B * nl * W) * _token_row_bytes(cfg, "bf16")
     d_eff, m_eff = mesh_shard_factors(layout, cfg, mesh_shape)
-    shards = d_eff * m_eff
+    kv_shards = d_eff * m_eff
     out["per_device"] = {
-        "global": out["global"] / shards,
-        "local": out["local"] / shards,
-        "total": out["total"] / shards,
-        "shards": shards,
+        "global": out["global"] / kv_shards,
+        "local": out["local"] / kv_shards,
+        "total": out["total"] / kv_shards,
+        "shards": kv_shards,
     }
-    out["interconnect"] = _interconnect_decode(layout, cfg, d_eff, m_eff)
+    out["interconnect"] = _interconnect_decode(layout, cfg, mesh_shape, d_eff,
+                                               shards or {})
     return out
 
 
 def chunk_read_bytes(layout: CacheLayout, cfg,
                      mesh_shape: Tuple[int, int] = (1, 1),
-                     chunk_width: int = 1) -> Dict[str, Any]:
+                     chunk_width: int = 1,
+                     shards: Optional[Dict[str, Tuple[int, int]]] = None,
+                     ) -> Dict[str, Any]:
     """KV bytes ONE chunked-prefill step reads from the live cache (one
     slot): global layers attend the full ``(S_max,)`` row at full precision
     — BGPP's progressive prediction is a decode-time saving; prefill
     reconstructs exact int8 K from every plane — and local ring layers
     gather their ``window``.  Eager admission reads nothing (the B=1
-    forward self-attends without touching the cache)."""
+    forward self-attends without touching the cache).  ``mesh_shape`` and
+    ``shards`` price the mesh columns as in :func:`decode_read_bytes`."""
     S, W = layout.max_seq, layout.local_window
     ng, nl = len(layout.global_layers), len(layout.local_layers)
     fmt_l = "int8" if layout.kv_format == "bgpp" else layout.kv_format
     g = ng * S * _token_row_bytes(cfg, layout.kv_format)
     loc = nl * W * _token_row_bytes(cfg, fmt_l)
     out: Dict[str, Any] = {"global": g, "local": loc, "total": g + loc}
-    # chunks run at B=1, so only the "model" head shard splits the reads;
-    # the attend all-gather moves the chunk's Hq*Dh lanes at cache dtype
-    d_eff, m_eff = mesh_shard_factors(layout, cfg, mesh_shape)
+    # chunks run at B=1, so only the "model" head shard splits the reads
+    _, m_eff = mesh_shard_factors(layout, cfg, mesh_shape)
     out["per_device"] = {"total": out["total"] / m_eff, "shards": m_eff}
-    attend = ((m_eff - 1) * chunk_width * cfg.num_heads * cfg.head_dim
-              * _cache_dtype_bytes(cfg) * (ng + nl))
-    # no paged write broadcast here: a B=1 chunk is replicated across
-    # "data" (batch of one cannot shard), so every data replica computes
-    # the chunk redundantly and writes its own pool copy locally
-    del d_eff
-    out["interconnect"] = {
-        "attend_allgather": attend,
-        "paged_write_bcast": 0.0,
-        "total": attend,
-    }
+    # a B=1 chunk is replicated across "data": every data replica computes
+    # it, reduces its own partial sums and writes its own pool copy (no
+    # paged write broadcast)
+    ic = _tp_allreduce(chunk_width, cfg, shards or {}, int(mesh_shape[0]))
+    ic["paged_write_bcast"] = 0.0
+    ic["total"] = sum(ic.values())
+    out["interconnect"] = ic
     return out
 
 

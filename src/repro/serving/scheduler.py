@@ -172,14 +172,15 @@ class Scheduler:
         self.prefill_kw = dict(prefill_kw or {})  # eager-path forward kwargs
         self.record_logits = record_logits
 
-        self.cache = kvc.init_cache_arrays(cfg, layout)
-        # mesh placement (tentpole): KV pools/stacks heads-parallel on
-        # "model" (slot stacks also batch-parallel on "data"), weights under
-        # the bit-exact serving policy, page table + allocator host-side and
-        # replicated.  Everything below is identity without a mesh.
+        # mesh placement: KV pools/stacks heads-parallel on "model" (slot
+        # stacks also batch-parallel on "data"), made there; weights
+        # tensor-parallel over "model" (serving.sharded), page table +
+        # allocator host-side and replicated.  Everything below is
+        # identity without a mesh.
+        self.cache = shd.init_cache(cfg, layout, rules)
         self.mesh_shape = shd.mesh_shape(rules)
+        shards = {}  # each projection's (row, column) shards, for pricing
         if rules.mesh is not None:
-            self.cache = shd.shard_cache(self.cache, cfg, layout, rules)
             if param_specs is None:
                 # a family without a spec tree raises here: replicating the
                 # weights instead would silently put the whole model on
@@ -187,6 +188,8 @@ class Scheduler:
                 from repro.models import model_zoo
                 param_specs = model_zoo.param_specs(cfg)
             self.params = shd.shard_params(params, param_specs, rules)
+            shards = shd.projection_shards(params, param_specs,
+                                           self.mesh_shape)
         # serve-time weight format (the once-dead knob): resolved ONCE here
         # (env > config, same contract as decode_kernel), projections
         # converted AFTER sharding so the int8/bstc records inherit the
@@ -309,12 +312,14 @@ class Scheduler:
         # this is the two-phase plan — bit-planes plus at most
         # ceil(keep_ratio·S) full-precision rows per (slot, layer) — the
         # counter stats()["kv_read"] and the serving benchmarks report.
-        self._decode_read = kvc.decode_read_bytes(layout, cfg, self.mesh_shape)
-        self._chunk_read = kvc.chunk_read_bytes(layout, cfg, self.mesh_shape)
+        self._decode_read = kvc.decode_read_bytes(layout, cfg, self.mesh_shape,
+                                                  shards)
+        self._chunk_read = kvc.chunk_read_bytes(layout, cfg, self.mesh_shape,
+                                                shards=shards)
         # chunk interconnect scales with the chunk's lane count; price per
         # valid lane (chunk_width=1) and multiply by tokens consumed
         self._chunk_ic_per_lane = kvc.chunk_read_bytes(
-            layout, cfg, self.mesh_shape, chunk_width=1
+            layout, cfg, self.mesh_shape, chunk_width=1, shards=shards
         )["interconnect"]["total"]
         self.decode_steps = 0
         self.kv_bytes_read = {"decode": 0.0, "prefill": 0.0,
@@ -323,9 +328,7 @@ class Scheduler:
         # contracts every converted projection once per batched decode
         # step, priced from the WeightPlan's coded layout (measured BSTC
         # stream bytes for fmt="bstc"); prefill reads the raw-dtype tree
-        self._weight_read = self.weight_plan.decode_read_bytes(
-            layout, cfg, self.mesh_shape
-        )
+        self._weight_read = self.weight_plan.decode_read_bytes(shards)
         self.weight_bytes_read = {"decode": 0.0, "prefill": 0.0}
         # audit trail for the chunk-budget contract: valid prompt tokens
         # prefilled between this step's admission and its decode
@@ -1045,8 +1048,10 @@ class Scheduler:
             "decode_bytes_reduction_vs_bf16": round(
                 dr["bf16_equiv"] / dr["total"], 3) if dr["total"] else None,
             # mesh columns: each device's share of the gathers, plus the
-            # explicitly priced collectives (attend all-gather, paged write
-            # broadcast) — zero / equal-to-total at mesh 1x1
+            # priced collectives (the two tensor-parallel all-reduces of
+            # each layer, the paged write broadcast) of one decode step,
+            # and of every executed decode step and chunk together —
+            # zero / equal-to-total at mesh 1x1
             "mesh": {"data": self.mesh_shape[0], "model": self.mesh_shape[1]},
             "kv_shards": dr["per_device"]["shards"],
             "decode_bytes_per_device_per_step": round(

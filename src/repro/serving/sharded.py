@@ -1,49 +1,67 @@
-"""Mesh placement for the serving stack — bit-exact by construction.
+"""Mesh placement for the serving stack.
 
-The serving oracle (tests/test_serving_fuzz.py) pins sharded runs to the
-single-device trace *bit-exactly* for bf16 caches.  That rules out the
-classic megatron placement wholesale: any weight whose model-mapped logical
-axis sits on the *contraction* side of its matmul (``wo``'s heads, the MLP
-down-projection's ff) would split a float reduction into a psum of shard
-partials, and float addition is not associative.  What remains safe is pure
-data movement:
+Serving meshes are ``(data, model)``.  ``"data"`` splits the batch of
+slots: each data group decodes its own rows with whole weights, so a
+data-only mesh computes exactly what one device computes.  ``"model"``
+splits every layer, Megatron style (:func:`serving_param_specs`):
 
-* **output-side (column-parallel) weights** — ``wq``/``wk``/``wv`` carry
-  HEADS/KV_HEADS on their *last* axis: each device computes its head slice
-  with the full-width d_model contraction, bit-identical to the unsharded
-  column.  Likewise ``lm_head``'s vocab columns.
-* **gather-side tables** — the embedding's vocab axis: a sharded token
-  lookup is a masked gather + an exact ``x + 0`` combine.
-* **the attend itself** — with q/k/v and the KV pools sharded on the same
-  head axis, every score/softmax/weighted-sum stays device-local per head;
-  the per-head outputs are *all-gathered* (concatenated, never summed)
-  across ``"model"`` before the replicated ``wo``.
+* **column-parallel** — ``wq``/``wk``/``wv`` and the MLP's ``gate``/``up``
+  carry HEADS/KV_HEADS/FF on their output (last) axis: each device
+  computes its heads' and its FF slice's activations from the whole
+  d_model row, no collective.
+* **the attend** — with q/k/v and the KV stacks or pools sharded on the
+  same head axis, every score, softmax and weighted sum stays on the
+  device that holds the head.
+* **row-parallel** — ``wo`` carries HEADS and the MLP's ``down`` FF on
+  their input (first) axis, so each device multiplies its own heads' or
+  FF slice's activations by its own rows and holds a partial sum of the
+  whole output.  One all-reduce per sublayer sums them
+  (``engine._row_parallel``: two per layer, ``tp.attn_out`` and
+  ``tp.mlp_out``).
+* **vocabulary** — the embedding's and ``lm_head``'s vocabulary axis: a
+  sharded token lookup is a masked gather and an exact ``x + 0`` combine;
+  the logits leave the step sharded by column.
 
-Everything else — ``wo``, the MLP stack, norms — stays replicated.  This
-module derives that placement from the model zoo's logical specs
-(:func:`serving_param_specs`) and owns the host→device placement of params
-and cache (:func:`shard_params` / :func:`shard_cache`) plus the mesh
-bookkeeping the scheduler's kv-read accounting reports.
+Norms stay replicated.  Every device holds ``1/model`` of every matrix.
+
+What stays exact: on a ``"model"`` axis of size 1 every one of these
+splits is the whole array, so every ``[1, 1]`` and data-only mesh holds
+whole weights on every device and decodes bit-identically to one device.
+What does not: with ``model > 1`` the two all-reduces of each layer add
+``model`` float partial sums, each a contraction over ``1/model`` of the
+input, where one device adds the whole contraction in one order.
+Float addition is not associative, so the outputs differ from a single
+device's by rounding: in float32 a few ulps of the sum, which the sharded
+parity oracle (``tests/test_serving_fuzz.py``) bounds.  Replicating
+``wo`` and the MLP instead (the earlier, exact placement) leaves each
+device three quarters of a 7B model's weights on a 1x4 mesh and no room
+for its cache, and reads them all every step.
+
+This module derives the placement from the model zoo's logical specs and
+owns the host->device placement of params and cache
+(:func:`shard_params` / :func:`init_cache` / :func:`shard_cache`) plus
+the mesh bookkeeping the scheduler's read accounting reports
+(:func:`mesh_shape`, :func:`projection_shards`).
 """
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+import functools
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AbstractMesh, NamedSharding, PartitionSpec as P
 
 from repro.distributed import sharding as sh
 from repro.launch.mesh import make_mesh
 from repro.serving import kv_cache as kvc
+from repro.serving import weights as swt
 
-# logical axes that may shard a weight only when they are the OUTPUT axis
-# (last dim) of their matmul — contraction-side occurrences must replicate
-_OUTPUT_ONLY = (sh.HEADS, sh.KV_HEADS)
-# logical axes that are safe wherever they appear (gather / output side)
-_ALWAYS = (sh.VOCAB,)
+# the logical axes the serving placement splits over "model", wherever they
+# sit in a leaf (every one maps to the "model" mesh axis)
+_TENSOR_PARALLEL = (sh.HEADS, sh.KV_HEADS, sh.FF, sh.VOCAB)
 
 
 def mesh_shape(rules: sh.ShardingRules) -> Tuple[int, int]:
@@ -58,22 +76,19 @@ def mesh_shape(rules: sh.ShardingRules) -> Tuple[int, int]:
 
 
 def serving_param_specs(specs):
-    """Restrict a logical param-spec tree to the bit-exact serving subset.
+    """Restrict a logical param-spec tree to the serving placement.
 
-    Keeps VOCAB anywhere and HEADS/KV_HEADS only on a leaf's last axis
-    (column-parallel); every other logical axis is dropped to replicated.
-    The result feeds :meth:`ShardingRules.tree_shardings`, whose
-    divisibility fallback (with a :class:`~repro.distributed.sharding
-    .ShardingFallbackWarning`) still applies per leaf.
+    Keeps HEADS/KV_HEADS/FF/VOCAB wherever they sit (:data:`_TENSOR_PARALLEL`:
+    ``wq``/``wk``/``wv``/``gate``/``up`` by column, ``wo``/``down`` by row,
+    the embedding and ``lm_head`` by vocabulary) and drops every other
+    logical axis (experts, d_model, norms) to replicated.  The result feeds
+    :meth:`ShardingRules.tree_shardings`, whose divisibility fallback (with
+    a :class:`~repro.distributed.sharding.ShardingFallbackWarning`) still
+    applies per leaf.
     """
 
     def fix(axes):
-        axes = tuple(axes)
-        last = len(axes) - 1
-        return tuple(
-            a if a in _ALWAYS or (a in _OUTPUT_ONLY and i == last) else None
-            for i, a in enumerate(axes)
-        )
+        return tuple(a if a in _TENSOR_PARALLEL else None for a in axes)
 
     return jax.tree.map(fix, specs, is_leaf=lambda x: isinstance(x, tuple))
 
@@ -90,8 +105,34 @@ def param_shardings(params, specs, rules: sh.ShardingRules):
     """NamedShardings of the serving policy for ``params`` (arrays or
     ``ShapeDtypeStruct``s) on the rules' mesh."""
     return rules.tree_shardings(
-        rules.mesh, serving_param_specs(specs), struct_tree=params
+        rules.mesh, serving_param_specs(specs), struct_tree=params,
     )
+
+
+def projection_shards(params, specs,
+                      mesh: Tuple[int, int]) -> Dict[str, Tuple[int, int]]:
+    """``{projection: (input-row shards, output-column shards)}`` of the
+    decode projections (:func:`repro.serving.weights.projection_paths`) as
+    :func:`param_shardings` places ``params`` (arrays or
+    ``ShapeDtypeStruct``s) on a ``(data, model)`` mesh of that shape, read
+    on an abstract mesh, divisibility fallback included.  A tied head is
+    the embedding transposed.  The weight and interconnect accounting
+    (``WeightPlan.decode_read_bytes``, ``kv_cache.decode_read_bytes``)
+    price from this, so they follow the placement itself."""
+    rules = sh.rules_for_mesh(
+        AbstractMesh(tuple(int(n) for n in mesh), ("data", "model")))
+    placed = param_shardings(params, specs, rules)
+
+    def ways(path):
+        shape = tuple(swt.get_path(params, path).shape)
+        shard = swt.get_path(placed, path).shard_shape(shape)
+        return shape[-2] // shard[-2], shape[-1] // shard[-1]
+
+    out = {proj: ways(path) for path, proj in swt.projection_paths(params)}
+    if "lm_head" not in params and "embed" in params:
+        rows, cols = ways(("embed",))
+        out["lm_head"] = (cols, rows)
+    return out
 
 
 def cache_shardings(cache, cfg, layout, rules: sh.ShardingRules):
@@ -103,6 +144,18 @@ def cache_shardings(cache, cfg, layout, rules: sh.ShardingRules):
     return rules.tree_shardings(
         rules.mesh, kvc.cache_specs(cfg, layout), struct_tree=cache
     )
+
+
+def init_cache(cfg, layout, rules: sh.ShardingRules):
+    """A zeroed serving cache (``kv_cache.init_cache_arrays``), made by one
+    jitted program straight into its placement on the rules' mesh, so no
+    device ever holds the whole cache (a 1x4 deepseek-7b cache of 32
+    slots x 4096 int8 positions is 30.9 GiB, 7.7 GiB a device)."""
+    make = functools.partial(kvc.init_cache_arrays, cfg, layout)
+    if rules is None or rules.mesh is None:
+        return make()
+    shardings = cache_shardings(jax.eval_shape(make), cfg, layout, rules)
+    return jax.jit(make, out_shardings=shardings)()
 
 
 def shard_cache(cache, cfg, layout, rules: sh.ShardingRules):

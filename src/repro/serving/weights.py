@@ -30,10 +30,9 @@ Accounting mirrors ``kv_cache.decode_read_bytes``: the scheduler holds a
 executed decode step into ``Scheduler.stats()["weight_read"]`` — totals,
 a bf16-equivalent denominator, a per-projection breakdown, closed-form
 modeled bytes (``repro.analysis.roofline.bstc_weight_traffic``, gated
-against the measured coded bytes at 1.0 ± 10%), and mesh columns reusing
-``kv_cache.mesh_shard_factors`` (wq/wk/wv and the vocab-sharded lm_head
-are column-parallel on ``"model"``; wo and the MLP are replicated under
-the bit-exact serving placement, so every device reads them whole).
+against the measured coded bytes at 1.0 ± 10%), and mesh columns priced
+from the shard counts the serving placement gives each projection
+(``repro.serving.sharded.projection_shards``).
 """
 
 from __future__ import annotations
@@ -50,7 +49,6 @@ import numpy as np
 from repro.analysis import roofline
 from repro.configs.base import WEIGHT_FORMATS
 from repro.core import bstc
-from repro.serving import kv_cache as kvc
 
 Tree = Dict[str, Any]
 
@@ -108,8 +106,9 @@ def quantize(w) -> Tree:
     weight (leading axes = stacked layer copies).
 
     ``scale = max|w| / 127`` over the input (contraction) axis — exact
-    elementwise math, so a column-sharded input yields an identically
-    valued (and identically sharded) record.
+    elementwise math and a max, so a column- or row-sharded input yields
+    an identically valued (and identically sharded) record; the scales
+    stay one per output column.
     """
     wf = jnp.asarray(w).astype(jnp.float32)
     scale = jnp.maximum(jnp.max(jnp.abs(wf), axis=-2), 1e-12) / 127.0
@@ -150,11 +149,7 @@ def check_serve_params(params: Tree, cfg, fmt: str) -> None:
 class WeightEntry:
     """One converted projection (all its stacked layer copies).
 
-    ``placement`` records the bit-exact serving shard: ``"heads"``
-    (column-parallel on ``"model"`` via the head-aligned last axis),
-    ``"vocab"`` (lm_head columns), or ``"replicated"`` (wo / MLP — their
-    model-mapped axes sit on the contraction side, which the bit-exact
-    policy never splits).  Byte columns cover ALL ``copies``.
+    Byte columns cover ALL ``copies``.
     """
 
     path: str
@@ -162,7 +157,6 @@ class WeightEntry:
     copies: int
     in_dim: int
     out_dim: int
-    placement: str
     coded_bytes: float
     int8_bytes: float
     bf16_bytes: float
@@ -197,33 +191,38 @@ class WeightPlan:
         """What raw-dtype leaves of the same geometry would read."""
         return self._sum("bf16_bytes")
 
-    def decode_read_bytes(self, layout, cfg,
-                          mesh_shape: Tuple[int, int] = (1, 1)) -> Dict[str, Any]:
-        """Weight bytes ONE batched ``serve_step`` reads, at static shapes.
+    def decode_read_bytes(
+            self, shards: Optional[Dict[str, Tuple[int, int]]] = None,
+    ) -> Dict[str, Any]:
+        """Per-step decode weight traffic, as the scheduler reports it.
 
         Mirrors :func:`repro.serving.kv_cache.decode_read_bytes`: totals,
         the bf16-equivalent denominator, per-projection breakdown, the
-        closed-form modeled bytes, and mesh columns.  Sharding reuses
-        :func:`repro.serving.kv_cache.mesh_shard_factors` — a ``"model"``
-        axis splits only the column-parallel entries (heads-aligned and
-        vocab-aligned last axes); replicated entries are read whole by
-        every device, and weights never shard over ``"data"``.
+        closed-form modeled bytes, and mesh columns.  ``shards`` maps a
+        projection to its (input-row, output-column) shard counts on the
+        mesh (:func:`repro.serving.sharded.projection_shards`; absent: one
+        device holds it whole), so a device reads ``1 / (rows * columns)``
+        of each entry.  The norms the plan leaves out are replicated.
         """
-        _, m_eff = kvc.mesh_shard_factors(layout, cfg, mesh_shape)
-        m = int(mesh_shape[1])
-        m_vocab = m if m >= 1 and cfg.vocab_size % m == 0 else 1
-        shards = {"heads": m_eff, "vocab": m_vocab, "replicated": 1}
-        sharded = sum(
-            e.coded_bytes for e in self.entries if shards[e.placement] > 1
-        )
-        replicated = self.total_bytes - sharded
-        per_dev = sum(
-            e.coded_bytes / shards[e.placement] for e in self.entries
-        )
+        shards = shards or {}
+
+        def ways(e: WeightEntry) -> int:
+            rows, cols = shards.get(e.proj, (1, 1))
+            return rows * cols
+
         per_proj: Dict[str, float] = {}
+        per_dev_proj: Dict[str, float] = {}
+        shards_proj: Dict[str, int] = {}
         for e in self.entries:
             per_proj[e.proj] = per_proj.get(e.proj, 0.0) + e.coded_bytes
-        out: Dict[str, Any] = {
+            per_dev_proj[e.proj] = (per_dev_proj.get(e.proj, 0.0)
+                                    + e.coded_bytes / ways(e))
+            shards_proj[e.proj] = ways(e)
+        sharded = sum(e.coded_bytes / ways(e) for e in self.entries
+                      if ways(e) > 1)
+        replicated = sum(e.coded_bytes for e in self.entries
+                         if ways(e) == 1)
+        return {
             "format": self.fmt,
             "total": self.total_bytes,
             "bf16_equiv": self.bf16_bytes,
@@ -231,24 +230,17 @@ class WeightPlan:
             "modeled": self._sum("modeled_bytes"),
             "per_projection": per_proj,
             "per_device": {
-                "sharded": sharded / max(m_eff, m_vocab, 1),
+                "sharded": sharded,
                 "replicated": replicated,
-                "total": per_dev,
-                "shards": m_eff,
+                "total": sharded + replicated,
+                "shards": max((ways(e) for e in self.entries), default=1),
             },
+            # the accounting-law surface: summing
+            # per_device_by_projection[p] * shards_by_projection[p] over
+            # projections recovers the total on every mesh
+            "per_device_by_projection": per_dev_proj,
+            "shards_by_projection": shards_proj,
         }
-        # exact per-placement split (the accounting-law surface): summing
-        # per_device_by_placement[p] * shards[p] over placements recovers
-        # the total, whatever mix of sharded/replicated entries exists
-        out["per_device_by_placement"] = {
-            p: sum(
-                e.coded_bytes / shards[p]
-                for e in self.entries if e.placement == p
-            )
-            for p in ("heads", "vocab", "replicated")
-        }
-        out["shards_by_placement"] = shards
-        return out
 
 
 # --------------------------------------------------------------------------
@@ -256,27 +248,27 @@ class WeightPlan:
 # --------------------------------------------------------------------------
 
 
-def _iter_targets(params: Tree) -> Iterator[Tuple[Tuple[str, ...], str, str]]:
-    """Yield ``(path, proj_name, placement)`` for every convertible leaf
-    present in the tree (explicit names only — biases/norms/MoE stay raw)."""
+def projection_paths(params: Tree) -> Iterator[Tuple[Tuple[str, ...], str]]:
+    """Yield ``(path, proj_name)`` for every convertible leaf present in
+    the tree (explicit names only — biases/norms/MoE stay raw)."""
     lay = params.get("layers")
     if isinstance(lay, dict):
         attn = lay.get("attn")
         if isinstance(attn, dict):
             for n in _ATTN_WEIGHTS:
                 if n in attn:
-                    yield (("layers", "attn", n), n,
-                           "replicated" if n == "wo" else "heads")
+                    yield ("layers", "attn", n), n
         mlp = lay.get("mlp")
         if isinstance(mlp, dict):
             for n in _MLP_WEIGHTS:
                 if n in mlp:
-                    yield (("layers", "mlp", n), n, "replicated")
+                    yield ("layers", "mlp", n), n
     if "lm_head" in params:
-        yield (("lm_head",), "lm_head", "vocab")
+        yield ("lm_head",), "lm_head"
 
 
-def _get(tree: Tree, path: Tuple[str, ...]):
+def get_path(tree: Tree, path: Tuple[str, ...]):
+    """The leaf at ``path`` (a :func:`projection_paths` path) of ``tree``."""
     for k in path:
         tree = tree[k]
     return tree
@@ -381,9 +373,8 @@ def prepare_serve_params(params: Tree, cfg, layout,
     serve = params
     checked_roundtrip = False
 
-    targets = list(_iter_targets(params))
-    for path, proj, placement in targets:
-        w = _get(params, path)
+    for path, proj in projection_paths(params):
+        w = get_path(params, path)
         shape = tuple(w.shape)
         copies = int(np.prod(shape[:-2], dtype=np.int64)) if len(shape) > 2 else 1
         in_dim, out_dim = int(shape[-2]), int(shape[-1])
@@ -392,7 +383,7 @@ def prepare_serve_params(params: Tree, cfg, layout,
         if fmt == "bf16":
             entries.append(WeightEntry(
                 path="/".join(path), proj=proj, copies=copies,
-                in_dim=in_dim, out_dim=out_dim, placement=placement,
+                in_dim=in_dim, out_dim=out_dim,
                 coded_bytes=bf16_b, int8_bytes=int8_b, bf16_bytes=bf16_b,
                 modeled_bytes=bf16_b,
             ))
@@ -418,7 +409,7 @@ def prepare_serve_params(params: Tree, cfg, layout,
                     checked_roundtrip = True
         entries.append(WeightEntry(
             path="/".join(path), proj=proj, copies=copies,
-            in_dim=in_dim, out_dim=out_dim, placement=placement,
+            in_dim=in_dim, out_dim=out_dim,
             coded_bytes=float(coded_b), int8_bytes=int8_b,
             bf16_bytes=bf16_b, modeled_bytes=float(modeled_b),
             bstc_fallback=fell_back,
@@ -433,7 +424,7 @@ def prepare_serve_params(params: Tree, cfg, layout,
         if fmt == "bf16":
             entries.append(WeightEntry(
                 path="embed.T", proj="lm_head", copies=1, in_dim=D,
-                out_dim=V, placement="vocab", coded_bytes=bf16_b,
+                out_dim=V, coded_bytes=bf16_b,
                 int8_bytes=int8_b, bf16_bytes=bf16_b, modeled_bytes=bf16_b,
             ))
         elif tied_head:
@@ -451,7 +442,7 @@ def prepare_serve_params(params: Tree, cfg, layout,
                     fell_back = True
             entries.append(WeightEntry(
                 path="embed.T", proj="lm_head", copies=1, in_dim=D,
-                out_dim=V, placement="vocab", coded_bytes=float(coded_b),
+                out_dim=V, coded_bytes=float(coded_b),
                 int8_bytes=int8_b, bf16_bytes=bf16_b,
                 modeled_bytes=float(modeled_b), bstc_fallback=fell_back,
             ))

@@ -236,8 +236,8 @@ print("OK", a.prefix_hit_tokens)
         """Structural: the shard_map-routed two-phase BGPP paged attend
         (phase-1 plane gathers + top-k + the phase-2 survivor gather)
         compiles to ZERO collectives on a 2x4 mesh — every step is local to
-        its head shard; the only cross-shard hop of the whole decode layer
-        is the attend-reduction all-gather outside it."""
+        its head shard; the decode layer's cross-shard hops are the
+        all-reduce after the row-parallel wo outside it and the MLP's."""
         out = run_py(
             """
 import dataclasses, re
@@ -276,6 +276,189 @@ print("OK")
             devices=8,
         )
         assert "OK" in out
+
+
+class TestTensorParallelServing:
+    """deepseek-7b smoke (4 q / 4 kv heads, d_ff 160: both divide 4) served
+    tensor-parallel on a forced 4-device [1, 4] mesh, and the placement
+    that puts 1/4 of every matrix on each device."""
+
+    def test_serve_matches_plain_reference(self):
+        """Chunked prefill, then decode through the int8 slot cache, on
+        the [1, 4] mesh, teacher-forced, against the benchmark's plain
+        float32 reference (bench/configs/dense_decoder.py) with the int8
+        KV arithmetic, on its seeded weights.
+
+        Tolerances: int8 KV rounds each q row, K/V row and weighted-
+        probability row to steps of 1/127 of the row's largest value, so
+        the float32 rounding change of the all-reduces (about 1e-6) can
+        move one value across a step boundary and the row's logits by a
+        step's share: 2.8e-3 measured at most, most rows within 3e-6.  The
+        same program in bfloat16, one precision below the float32 smoke
+        configuration, reads 0.079-0.128 at most and an rms error of
+        0.018-0.019.  The limits sit near the geometric middles: 1.5e-2
+        at most, 3e-3 rms per request."""
+        out = run_py(
+            f"""
+import sys
+sys.path.insert(0, {REPO!r})
+import numpy as np, jax, jax.numpy as jnp
+from bench import system
+from bench.spec import CODE, load_module
+from repro.configs import get_config
+from repro.serving import kv_cache as kvc, sharded as shd
+from repro.serving.request import Request
+from repro.serving.scheduler import Scheduler
+
+ref = load_module(CODE / "configs" / "dense_decoder.py")
+cfg = get_config("deepseek-7b", smoke=True)
+w = ref.init_weights(system.seed_key(2 ** 31 + 11), system.weight_shapes(cfg))
+conf = {{"hidden_size": cfg.d_model, "num_attention_heads": cfg.num_heads,
+        "num_key_value_heads": cfg.num_kv_heads, "head_dim": cfg.head_dim,
+        "rms_norm_eps": 1e-6, "rope_theta": cfg.rope_theta,
+        "kv_format": "int8"}}
+lay = kvc.layout_for(cfg, 4, 48, kv_format="int8", layout="slot")
+rng = np.random.default_rng(0)
+plan = [(rng.integers(0, cfg.vocab_size, int(n)).astype(np.int32),
+         rng.integers(0, cfg.vocab_size, 6).astype(np.int32), int(a))
+        for n, a in zip(rng.integers(5, 21, 4), (0, 0, 2, 5))]
+s = Scheduler(w, cfg, lay, shd.rules_for(1, 4), chunk_budget=8,
+              record_logits=True)
+for i, (p, f, a) in enumerate(plan):
+    s.submit(Request(rid=i, prompt=p, max_new_tokens=len(f),
+                     arrival_step=a, forced_tokens=f))
+s.run(max_steps=500)
+got = {{r.rid: np.stack(r.logit_rows) for r in s.finished}}
+assert sorted(got) == [0, 1, 2, 3]
+assert max(len(p) for p, _, _ in plan) > 8  # some prompt takes two chunks
+ic = s.stats()["kv_read"]
+assert ic["mesh"] == {{"data": 1, "model": 4}} and ic["interconnect_bytes"] > 0
+for i, (p, f, _) in enumerate(plan):
+    seq = np.concatenate([p, f[:-1]])
+    want = np.asarray(ref.logits(w, ref.conf_items(conf),
+                                 jnp.asarray(seq)))[len(p) - 1:]
+    d = got[i] - want
+    assert float(np.max(np.abs(d))) <= 1.5e-2, (i, float(np.max(np.abs(d))))
+    assert float(np.sqrt(np.mean(d ** 2))) <= 3e-3, i
+print("OK")
+"""
+        )
+        assert "OK" in out
+
+    def test_each_device_holds_a_quarter_of_every_matrix(self):
+        out = run_py(
+            """
+import numpy as np, jax
+from repro.configs import get_config
+from repro.models import model_zoo
+from repro.serving import sharded as shd
+
+cfg = get_config("deepseek-7b", smoke=True)
+params, specs = model_zoo.init(jax.random.key(0), cfg)
+placed = shd.shard_params(params, specs, shd.rules_for(1, 4))
+flat = jax.tree_util.tree_flatten_with_path(placed)[0]
+split = {"wq": -1, "wk": -1, "wv": -1, "gate": -1, "up": -1, "wo": 1,
+         "down": 1, "embed": 0, "lm_head": 1}
+total = whole = 0
+for path, x in flat:
+    name = jax.tree_util.keystr(path)
+    leaf = path[-1].key
+    shard = x.sharding.shard_shape(x.shape)
+    total += x.nbytes
+    if "norm" in name:
+        assert shard == x.shape, name  # norms replicated
+        whole += x.nbytes
+        continue
+    ax = split[leaf]
+    want = list(x.shape)
+    want[ax] //= 4
+    assert list(shard) == want, (name, shard, x.shape)
+held = {d: 0 for d in jax.devices()[:4]}
+for x in jax.tree.leaves(placed):
+    for sh_ in x.addressable_shards:
+        held[sh_.device] += sh_.data.nbytes
+assert set(held.values()) == {(total - whole) // 4 + whole}, held
+print("OK")
+"""
+        )
+        assert "OK" in out
+
+    def test_only_collectives_are_two_allreduces_per_layer(self):
+        """The decode step and a prefill chunk on [1, 4]: the attend keeps
+        every head on its device (no all-gather, no all-to-all), and each
+        layer's only collectives are the float32 all-reduces named
+        ``tp.attn_out`` and ``tp.mlp_out``; the one other is the
+        vocabulary-sharded embedding lookup's exact combine."""
+        out = run_py(
+            r"""
+import re
+import jax, jax.numpy as jnp
+from repro.configs import get_config
+from repro.models import model_zoo
+from repro.serving import engine, kv_cache as kvc, sharded as shd
+
+cfg = get_config("deepseek-7b", smoke=True)
+rules = shd.rules_for(1, 4)
+lay = kvc.layout_for(cfg, 4, 48, kv_format="int8", layout="slot")
+params, specs = model_zoo.init(jax.random.key(0), cfg)
+params = shd.shard_params(params, specs, rules)
+cache = shd.shard_cache(kvc.init_cache_arrays(cfg, lay), cfg, lay, rules)
+i32 = lambda v: jnp.asarray(v, jnp.int32)
+progs = {
+    "serve_step": jax.jit(engine.make_serve_step(cfg, lay, rules)).lower(
+        params, cache, jnp.zeros((4, 1), jnp.int32)),
+    "prefill_chunk": jax.jit(engine.make_prefill_chunk(cfg, lay, rules))
+    .lower(params, cache, jnp.zeros((1, 8), jnp.int32), i32(0), i32(0),
+           i32(8)),
+}
+kinds = r"(all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all)"
+for name, low in progs.items():
+    ops = [l for l in low.compile().as_text().splitlines()
+           if re.search(r"= \S+ " + kinds + r"(-start)?\(", l)]
+    by = {}
+    for l in ops:
+        kind = re.search(kinds, l.split("=", 1)[1]).group(1)
+        scope = re.search(r'op_name="([^"]*)"', l).group(1)
+        tag = next((t for t in ("tp.attn_out", "tp.mlp_out") if t in scope),
+                   "other")
+        assert kind == "all-reduce", (name, l)
+        if tag != "other":
+            assert " f32[" in l, (name, l)
+        by[tag] = by.get(tag, 0) + 1
+    assert by.pop("tp.attn_out") == by.pop("tp.mlp_out") == cfg.num_layers, (
+        name, by)
+    assert by.get("other", 0) <= 1, (name, by)
+print("OK")
+"""
+        )
+        assert "OK" in out
+
+    def test_1x1_specs_unchanged(self):
+        """A "model" axis of size 1 splits nothing: on [1, 1], as on the
+        data-only meshes [2, 1] and [4, 1], every weight is whole on every
+        device — the placement every one-chip program compiled to — and
+        every projection prices as unsplit (abstract meshes: shapes only)."""
+        import jax
+        from jax.sharding import AbstractMesh
+        from repro.configs import get_config
+        from repro.distributed import sharding as sh
+        from repro.models import model_zoo
+        from repro.serving import sharded as shd
+
+        for arch in ("deepseek-7b", "phi4-mini-3.8b"):
+            cfg = get_config(arch, smoke=True)
+            shapes = jax.eval_shape(lambda k: model_zoo.init(k, cfg)[0],
+                                    jax.random.key(0))
+            specs = model_zoo.param_specs(cfg)
+            for mesh in ((1, 1), (2, 1), (4, 1)):
+                rules = sh.rules_for_mesh(AbstractMesh(mesh, ("data", "model")))
+                placed = shd.param_shardings(shapes, specs, rules)
+                for s_, x in zip(jax.tree.leaves(placed),
+                                 jax.tree.leaves(shapes)):
+                    assert s_.is_fully_replicated, (arch, mesh, s_)
+                    assert s_.shard_shape(x.shape) == x.shape
+                assert set(shd.projection_shards(
+                    shapes, specs, mesh).values()) == {(1, 1)}, (arch, mesh)
 
 
 class TestDryRunCell:

@@ -1,5 +1,7 @@
 """Property-based tests of system invariants (hypothesis)."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,6 +14,7 @@ from repro.configs.base import ModelConfig
 from repro.core import attention, bstc
 from repro.models import model_zoo, moe
 from repro.serving import kv_cache as kvc
+from repro.serving import sharded as shd
 from repro.serving import weights as swt
 from repro.serving.request import Request
 from repro.serving.scheduler import Scheduler
@@ -146,15 +149,27 @@ class TestBGPPKernelTrafficModel:
         assert r[0] > r[1] > r[2] > r[3]
 
 
+def _projection_shards(cfg, mesh):
+    """What the serving placement makes of ``cfg``'s projections on a
+    ``(data, model)`` mesh (shapes only: no devices needed)."""
+    shapes = jax.eval_shape(lambda k: model_zoo.init(k, cfg)[0],
+                            jax.random.key(0))
+    return shd.projection_shards(shapes, model_zoo.param_specs(cfg), mesh)
+
+
 class TestKVReadAccountingLaws:
     """Laws of the mesh columns in the kv-read accounting
     (kv_cache.decode_read_bytes / chunk_read_bytes): per-device shares
     recombine to the single-device totals, interconnect bytes vanish
-    exactly at mesh 1x1, and the attend all-gather grows monotonically
-    with the "model" size."""
+    exactly at mesh 1x1, and the two tensor-parallel all-reduces of each
+    layer, priced from the placement's shard counts, grow monotonically
+    with the "model" size and match a hand count."""
 
     # deepseek smoke: 4 q / 4 kv heads — divisible by every model size here
     CFG = get_config("deepseek-7b", smoke=True)
+
+    def _shards(self, mesh):
+        return _projection_shards(self.CFG, mesh)
 
     def _layout(self, fmt, layout, slots=4):
         return kvc.layout_for(self.CFG, slots, 48, kv_format=fmt,
@@ -185,33 +200,73 @@ class TestKVReadAccountingLaws:
     def test_interconnect_zero_at_1x1(self, fmt, layout):
         lay = self._layout(fmt, layout)
         for reader in (kvc.decode_read_bytes, kvc.chunk_read_bytes):
-            ic = reader(lay, self.CFG, (1, 1))["interconnect"]
+            ic = reader(lay, self.CFG, (1, 1),
+                        shards=self._shards((1, 1)))["interconnect"]
             assert ic["total"] == 0.0, (reader.__name__, ic)
 
     @given(st.sampled_from(["bf16", "int8", "bgpp"]),
            st.sampled_from(["slot", "paged"]))
     @settings(max_examples=6, deadline=None)
     def test_attend_allgather_monotone_in_model(self, fmt, layout):
-        """The attend reduction's all-gather moves (m_eff - 1)/m_eff of the
-        head outputs — strictly more bytes at every larger dividing model
-        size."""
+        """Each ring all-reduce moves 2 (m - 1) / m of its rows from each
+        of m devices — strictly more bytes at every larger dividing model
+        size, after wo and after the MLP alike, in decode and in a
+        chunk."""
         lay = self._layout(fmt, layout)
-        ag = [kvc.decode_read_bytes(lay, self.CFG, (1, m))["interconnect"]
-              ["attend_allgather"] for m in (1, 2, 4)]
-        assert ag[0] == 0.0
-        assert ag[0] < ag[1] < ag[2]
+        for key in ("attn_out_allreduce", "mlp_out_allreduce"):
+            dec = [kvc.decode_read_bytes(lay, self.CFG, (1, m),
+                                         self._shards((1, m)))
+                   ["interconnect"][key] for m in (1, 2, 4)]
+            ck = [kvc.chunk_read_bytes(lay, self.CFG, (1, m), chunk_width=8,
+                                       shards=self._shards((1, m)))
+                  ["interconnect"][key] for m in (1, 2, 4)]
+            assert dec[0] == ck[0] == 0.0
+            assert dec[0] < dec[1] < dec[2]
+            assert ck[0] < ck[1] < ck[2]
+
+    @pytest.mark.parametrize("mesh", [(1, 2), (1, 4), (2, 4), (4, 2)])
+    def test_allreduce_bytes_by_hand(self, mesh):
+        """Two float32 all-reduces of (rows, d_model) per layer, each moving
+        2 (m - 1) rows x d_model x 4 bytes over a "model" group: the
+        decode step's B rows split over "data"; a B=1 chunk's C rows
+        reduced again by every data replica."""
+        d, m = mesh
+        lay = self._layout("int8", "slot")
+        L, D = self.CFG.num_layers, self.CFG.d_model
+        shards = self._shards(mesh)
+        ic = kvc.decode_read_bytes(lay, self.CFG, mesh,
+                                   shards)["interconnect"]
+        want = 2 * (m - 1) * lay.batch * D * 4 * L
+        assert ic["attn_out_allreduce"] == ic["mlp_out_allreduce"] == want
+        assert ic["total"] == 2 * want
+        ck = kvc.chunk_read_bytes(lay, self.CFG, mesh, chunk_width=16,
+                                  shards=shards)["interconnect"]
+        assert ck["attn_out_allreduce"] == 2 * (m - 1) * 16 * D * 4 * L * d
+        assert ck["total"] == 2 * ck["attn_out_allreduce"]
 
     def test_indivisible_shapes_fall_back_to_replication(self):
         # phi4 smoke: 6 q / 2 kv heads — neither divides model=4, so the
-        # model factor must collapse to 1 (pool replicated, no interconnect)
+        # KV model factor must collapse to 1 (pool replicated); wo's 96
+        # input rows and the MLP's 192 FF still divide 4, so both
+        # all-reduces remain, and the unsplit batch of 3 is reduced by
+        # both data replicas
         cfg = get_config("phi4-mini-3.8b", smoke=True)
         lay = kvc.layout_for(cfg, 3, 48, kv_format="bf16", layout="slot")
         d_eff, m_eff = kvc.mesh_shard_factors(lay, cfg, (2, 4))
         assert m_eff == 1
         assert d_eff == 1  # batch 3 does not divide data=2 either
-        out = kvc.decode_read_bytes(lay, cfg, (2, 4))
+        out = kvc.decode_read_bytes(lay, cfg, (2, 4),
+                                    _projection_shards(cfg, (2, 4)))
         assert out["per_device"]["shards"] == 1
-        assert out["interconnect"]["total"] == 0.0
+        ring = 2 * 3 * 3 * cfg.d_model * 4 * cfg.num_layers * 2
+        assert out["interconnect"]["attn_out_allreduce"] == ring
+        assert out["interconnect"]["total"] == 2 * ring
+        # a d_ff that 4 does not divide keeps down replicated: nothing to sum
+        odd = dataclasses.replace(cfg, d_ff=cfg.d_ff + 2)
+        shards = _projection_shards(odd, (2, 4))
+        assert shards["down"] == shards["up"] == (1, 1)
+        ic = kvc.decode_read_bytes(lay, odd, (2, 4), shards)["interconnect"]
+        assert ic["mlp_out_allreduce"] == 0.0 and ic["attn_out_allreduce"]
 
     def test_chunk_paged_write_broadcast_is_zero(self):
         """B=1 prefill chunks are replicated over "data": every replica
@@ -220,16 +275,17 @@ class TestKVReadAccountingLaws:
         rows live on distinct data shards)."""
         lay = self._layout("int8", "paged")
         for mesh in ((2, 1), (2, 4), (4, 2)):
-            ck = kvc.chunk_read_bytes(lay, self.CFG, mesh)
+            shards = self._shards(mesh)
+            ck = kvc.chunk_read_bytes(lay, self.CFG, mesh, shards=shards)
             assert ck["interconnect"]["paged_write_bcast"] == 0.0
-            dk = kvc.decode_read_bytes(lay, self.CFG, mesh)
+            dk = kvc.decode_read_bytes(lay, self.CFG, mesh, shards)
             assert dk["interconnect"]["paged_write_bcast"] > 0.0
 
 
 class TestWeightReadAccountingLaws:
     """Laws of the serve-time weight-read plan (repro.serving.weights):
-    per-placement device shares recombine to the single-device total on
-    every mesh, the bf16 plan prices the raw dense bytes exactly, bstc
+    per-projection device shares, priced from the placement's shard
+    counts, recombine to the single-device total on every mesh, the bf16 plan prices the raw dense bytes exactly, bstc
     coding halves (better) the bf16 traffic at the paper's bit-level
     sparsity, and the closed-form traffic model is the measured stream's
     formula (the bench's ±10% reconciliation gate rides on that)."""
@@ -246,7 +302,7 @@ class TestWeightReadAccountingLaws:
             params, _ = model_zoo.init(jax.random.key(0), cfg)
             lay = kvc.layout_for(cfg, 4, 48, kv_format="bf16")
             _, plan = swt.prepare_serve_params(params, cfg, lay, fmt)
-            cls._CACHE[fmt] = (cfg, lay, plan)
+            cls._CACHE[fmt] = (cfg, params, plan)
         return cls._CACHE[fmt]
 
     @given(
@@ -255,16 +311,36 @@ class TestWeightReadAccountingLaws:
     )
     @settings(max_examples=18, deadline=None)
     def test_per_device_times_shards_is_total(self, fmt, mesh):
-        cfg, lay, plan = self._plan(fmt)
-        out = plan.decode_read_bytes(lay, cfg, mesh)
+        cfg, params, plan = self._plan(fmt)
+        out = plan.decode_read_bytes(
+            shd.projection_shards(params, model_zoo.param_specs(cfg), mesh))
         recomposed = sum(
-            out["per_device_by_placement"][p] * out["shards_by_placement"][p]
-            for p in out["per_device_by_placement"]
+            out["per_device_by_projection"][p]
+            * out["shards_by_projection"][p]
+            for p in out["per_device_by_projection"]
         )
         np.testing.assert_allclose(recomposed, out["total"])
         np.testing.assert_allclose(out["total"], plan.total_bytes)
         np.testing.assert_allclose(
             sum(out["per_projection"].values()), out["total"])
+        # tensor parallel: every projection divides the model size here,
+        # so each device reads 1/model of the whole plan
+        np.testing.assert_allclose(out["per_device"]["total"],
+                                   out["total"] / mesh[1])
+        assert out["per_device"]["replicated"] == (
+            0.0 if mesh[1] > 1 else out["total"])
+
+    @pytest.mark.parametrize("proj,placement", [
+        ("wq", "column"), ("wk", "column"), ("wv", "column"), ("wo", "row"),
+        ("gate", "column"), ("up", "column"), ("down", "row"),
+        ("lm_head", "vocab")])
+    def test_placement_of_each_projection(self, proj, placement):
+        """On [1, 4] each projection splits 4 ways along the axis its
+        placement names: (input-row, output-column) shards."""
+        cfg, params, _ = self._plan("bf16")
+        want = {"column": (1, 4), "row": (4, 1), "vocab": (1, 4)}[placement]
+        assert shd.projection_shards(
+            params, model_zoo.param_specs(cfg), (1, 4))[proj] == want
 
     def test_bf16_plan_prices_dense_bytes_exactly(self):
         cfg, _, plan = self._plan("bf16")
@@ -274,11 +350,11 @@ class TestWeightReadAccountingLaws:
             assert e.coded_bytes == e.bf16_bytes == want, e.path
 
     def test_bstc_coded_at_most_half_of_bf16(self):
-        cfg, lay, plan = self._plan("bstc")
+        _, _, plan = self._plan("bstc")
         assert plan.total_bytes <= plan.bf16_bytes / 2, (
             "BSTC coding must at least halve bf16 weight traffic at the "
             "paper's bit-level sparsity")
-        out = plan.decode_read_bytes(lay, cfg, (1, 1))
+        out = plan.decode_read_bytes()
         assert 0.9 <= out["total"] / out["modeled"] <= 1.1, (
             "measured coded stream must reconcile with the closed form")
 

@@ -70,6 +70,15 @@ SLOTS = 2
 # (b=1 AND h=1), the mesh analogue of the fixed-batch-shape caveat on
 # _compare_to_alone_runs
 MESH_SLOTS = 4
+# logits of a tensor-parallel ("model" > 1) run against one device's: the
+# two all-reduces of each layer add 'model' float32 partial sums of wo's
+# and the MLP down projection's contraction where one device adds it in
+# one order, a rounding change of a few float32 units (2**-23 relative)
+# per sublayer.  Four of them (two layers) carried through the head to the
+# smoke model's logits (largest about 3.7) measured 2.5e-6 on the bf16
+# trace; 1e-5, about 20 units of the largest logit, keeps 4x of room and
+# is the budget the teacher-forced int8/bgpp oracle already holds
+TP_TOL = 1e-5
 MESHES = [(1, 1), (2, 1), (1, 4), (2, 4)]
 PAGE_SIZE = 8
 CHUNK_BUDGET = 6  # buckets (4, 6): lengths 3..20 hit off-bucket/exact/multi
@@ -183,7 +192,7 @@ def _run(cfg, params, layout, reqs, shared=None, admission="chunked",
 
 def _compare_to_alone_runs(cfg, params, reqs, joint, arch_key, kv_format,
                            layout, joint_shared=None, slots=SLOTS,
-                           admission="chunked", alone_kw=None):
+                           admission="chunked", alone_kw=None, exact=None):
     """Re-run each request alone on the SLOT layout and compare — the slot
     path is the oracle for both layouts.  ``joint_shared``: the joint
     scheduler's compiled fns, reusable only when the joint run itself was
@@ -193,8 +202,11 @@ def _compare_to_alone_runs(cfg, params, reqs, joint, arch_key, kv_format,
     (cache-attend) prefills produce their first-token logits through
     different float paths, so each admission mode oracles against itself.
     ``alone_kw``: extra Scheduler kwargs for the alone runs — the spec
-    axis pins them non-speculative regardless of REPRO_SPEC_DECODE."""
-    exact = kv_format == "bf16"
+    axis pins them non-speculative regardless of REPRO_SPEC_DECODE.
+    ``exact`` (default: bf16 caches) demands bit-identical logits; else
+    they agree within 1e-5 (:data:`TP_TOL` for a tensor-parallel joint
+    run)."""
+    exact = kv_format == "bf16" if exact is None else exact
     slot_layout = _layout_for(cfg, kv_format, "slot", slots=slots)
     shared = joint_shared
     for r in reqs:
@@ -590,18 +602,20 @@ def _sharded_parity_oracle(kv_format, layout, mesh, seed,
                            check_alone_runs=False):
     """Run the SAME request trace through a (data, model)-meshed scheduler
     and through a single-device one, and demand the joint traces match —
-    bit-exactly for bf16 caches, within 1e-5 for int8/bgpp (teacher-forced,
-    as in the base oracle).  Also audits the mesh columns of the kv_read
-    counter: interconnect bytes are zero exactly at 1x1, positive whenever
-    the heads actually shard, and the per-device column recombines to the
-    single-device total."""
+    bit-exactly for bf16 caches on a data-only mesh, within 1e-5 for
+    int8/bgpp (teacher-forced, as in the base oracle) and within
+    :data:`TP_TOL` wherever ``model > 1`` sums row-parallel partials; the
+    greedy tokens of a bf16 cache must match on every mesh.  Also audits
+    the mesh columns of the kv_read counter: interconnect bytes are zero
+    exactly at 1x1, positive whenever the layers actually shard, and the
+    per-device column recombines to the single-device total."""
     d, m = mesh
     if jax.device_count() < d * m:
         pytest.skip(f"mesh {d}x{m} needs {d * m} host devices; set "
                     "XLA_FLAGS=--xla_force_host_platform_device_count=8")
     seed = int(os.environ.get("REPRO_FUZZ_SEED", seed))
     cfg, params = _model("mesh")
-    exact = kv_format == "bf16"
+    exact = kv_format == "bf16" and m == 1
     reqs, want = _mesh_base_run(kv_format, layout, seed)
     meta = {"oracle": "sharded-parity", "arch": "mesh",
             "kv_format": kv_format, "layout": layout,
@@ -625,11 +639,11 @@ def _sharded_parity_oracle(kv_format, layout, mesh, seed,
                     )
                 else:
                     err = float(np.max(np.abs(a - b)))
-                    assert err <= 1e-5, (
+                    assert err <= TP_TOL, (
                         f"{kv_format}/{layout}@{d}x{m} rid {r.rid} "
                         f"token {t}: |d|={err}"
                     )
-            if exact:
+            if kv_format == "bf16":
                 assert g.generated == w.generated, (
                     f"{kv_format}/{layout}@{d}x{m} rid {r.rid}: greedy "
                     f"tokens diverge under sharding"
@@ -641,14 +655,15 @@ def _sharded_parity_oracle(kv_format, layout, mesh, seed,
         if (d, m) == (1, 1):
             assert kv["interconnect_bytes_per_step"] == 0
             assert kv["interconnect_bytes"] == 0
-        elif m > 1:  # heads actually shard: the attend all-gather is priced
+        elif m > 1:  # layers actually shard: the all-reduces are priced
             assert kv["interconnect_bytes_per_step"] > 0
             assert kv["interconnect_bytes"] > 0
         if check_alone_runs:
             # the satellite contract: the SHARDED joint trace itself is
             # also pinned to single-device slot-layout alone runs
             _compare_to_alone_runs(cfg, params, reqs, got, "mesh",
-                                   kv_format, layout, slots=MESH_SLOTS)
+                                   kv_format, layout, slots=MESH_SLOTS,
+                                   exact=exact)
 
 
 @pytest.mark.parametrize("layout", ["slot", "paged"])
