@@ -61,8 +61,9 @@ def run():
     for mode, tag in (("interpret", "interp"), ("ref", "ref")):
         us = time_fn(
             lambda m=mode: paged_flash_decode(
-                q, k_q, v_q, table, pos, page_size=page,
-                k_scale=ks, v_scale=vs, mode=m,
+                q, k_q.reshape(1, B * S, Hk * Dh),
+                v_q.reshape(1, B * S, Hk * Dh), table, pos, 0,
+                page_size=page, k_scale=ks[None], v_scale=vs[None], mode=m,
             ),
             iters=3, warmup=1,
         )

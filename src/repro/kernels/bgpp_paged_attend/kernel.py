@@ -272,7 +272,7 @@ def bgpp_paged_attend_pallas(
     slot4 = lambda b, j, pids_, pos_: (b, 0, 0, 0)
     slot3 = lambda b, j, pids_, pos_: (b, 0, 0)
     page2 = lambda b, j, pids_, pos_: (pids_[b, j], 0)
-    rows_of = lambda x: slot_scale_rows(x, page_ids, P)[:, :, 0]  # (B, Hk, S)
+    rows_of = lambda x: slot_scale_rows(x, page_ids, P)  # (B, Hk, S)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, pp),

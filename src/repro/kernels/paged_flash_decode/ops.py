@@ -11,6 +11,7 @@ import functools
 from typing import Optional
 
 import jax
+import jax.numpy as jnp
 
 from repro.kernels import dispatch
 from repro.kernels.paged_flash_decode.kernel import paged_flash_decode_pallas
@@ -19,30 +20,30 @@ from repro.kernels.paged_flash_decode.ref import paged_flash_decode_ref
 
 @functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
 def _paged_flash_pallas_path(
-    q, k, v, page_ids, pos, *,
+    q, k, v, page_ids, pos, layer, *,
     page_size: int,
     k_scale=None, v_scale=None,
     interpret: bool = False,
 ):
     return paged_flash_decode_pallas(
-        q, k, v, page_ids, pos, page_size=page_size,
+        q, k, v, page_ids, pos, layer, page_size=page_size,
         k_scale=k_scale, v_scale=v_scale, interpret=interpret,
     )
 
 
 @functools.partial(jax.jit, static_argnames=("page_size",))
-def _paged_flash_ref_jit(q, k, v, page_ids, pos, *, page_size,
+def _paged_flash_ref_jit(q, k, v, page_ids, pos, layer, *, page_size,
                          k_scale=None, v_scale=None):
     return paged_flash_decode_ref(
-        q, k, v, page_ids, pos, page_size=page_size,
+        q, k, v, page_ids, pos, layer, page_size=page_size,
         k_scale=k_scale, v_scale=v_scale,
     )
 
 
-def _paged_flash_ref_path(q, k, v, page_ids, pos, *, page_size,
+def _paged_flash_ref_path(q, k, v, page_ids, pos, layer, *, page_size,
                           k_scale=None, v_scale=None):
     return _paged_flash_ref_jit(
-        q, k, v, page_ids, pos, page_size=page_size,
+        q, k, v, page_ids, pos, layer, page_size=page_size,
         k_scale=k_scale, v_scale=v_scale,
     )
 
@@ -57,20 +58,16 @@ def _validate(q, k, v, page_ids, pos, page_size, k_scale, v_scale):
     B, Hk, g, D = q.shape
     if k.ndim != 3 or k.shape != v.shape:
         raise ValueError(
-            f"paged_flash_decode: pools must be token-major (n_tok, Hk, D); "
-            f"got k {k.shape} vs v {v.shape}"
+            f"paged_flash_decode: K/V must be layer stacks of token rows "
+            f"(L, n_tok, Hk*D); got k {k.shape} vs v {v.shape}"
         )
-    n_tok = k.shape[0]
-    if k.shape[1] != Hk:
+    L, n_tok = k.shape[:2]
+    if k.shape[2] != Hk * D:
         raise ValueError(
-            f"paged_flash_decode: q carries Hk={Hk} kv heads but the pool "
-            f"carries {k.shape[1]} — under shard_map both operands must be "
-            f"the SAME device-local head shard"
-        )
-    if k.shape[2] != D:
-        raise ValueError(
-            f"paged_flash_decode: head_dim mismatch q D={D} vs pool "
-            f"D={k.shape[2]}"
+            f"paged_flash_decode: q carries Hk={Hk} kv heads of D={D} but "
+            f"the pool rows hold {k.shape[2]} lanes, not Hk*D — under "
+            f"shard_map both operands must be the SAME device-local head "
+            f"shard"
         )
     if page_size < 1 or n_tok % page_size:
         raise ValueError(
@@ -85,21 +82,26 @@ def _validate(q, k, v, page_ids, pos, page_size, k_scale, v_scale):
     if (k_scale is None) != (v_scale is None):
         raise ValueError(
             "paged_flash_decode: int8 pools need BOTH k_scale and v_scale "
-            "(n_tok, Hk) — got exactly one"
+            "— got exactly one"
         )
-    if k_scale is not None and k_scale.shape != (n_tok, Hk):
-        raise ValueError(
-            f"paged_flash_decode: scales must be (n_tok={n_tok}, Hk={Hk}); "
-            f"got {k_scale.shape}"
-        )
+    S = page_ids.shape[1] * page_size
+    forms = ((L, n_tok, Hk), (L, B, Hk, S))
+    for s in (k_scale, v_scale):
+        if s is not None and s.shape not in forms:
+            raise ValueError(
+                f"paged_flash_decode: scales must be token-major pools "
+                f"{forms[0]} or heads-major per-slot rows {forms[1]}; got "
+                f"{s.shape}"
+            )
 
 
 def paged_flash_decode(
     q: jax.Array,  # (B, Hk, g, D) f32 grouped decode query
-    k: jax.Array,  # (n_tok, Hk, D)
-    v: jax.Array,  # (n_tok, Hk, D)
+    k: jax.Array,  # (L, n_tok, Hk*D) every layer's token rows
+    v: jax.Array,  # (L, n_tok, Hk*D)
     page_ids: jax.Array,  # (B, pages_per_slot) int32, -1 = unmapped
     pos: jax.Array,  # (B,) int32 last valid logical position per slot
+    layer,  # int or () int32: the layer of the stack to attend
     *,
     page_size: int,
     k_scale: Optional[jax.Array] = None,
@@ -107,18 +109,21 @@ def paged_flash_decode(
     interpret: bool = False,
     mode: Optional[str] = None,
 ) -> jax.Array:
-    """Page-table-aware single-token flash decode -> f32 ``(B, Hk, g, D)``.
+    """Page-table-aware single-token flash decode of layer ``layer`` of the
+    stacked cache -> f32 ``(B, Hk, g, D)``.
 
     bf16/f32 pools run the dense attend; passing ``k_scale``/``v_scale``
-    selects the int8 A2/A3 path.  Routing between compiled / interpret /
-    ref is governed by :mod:`repro.kernels.dispatch`.
+    selects the int8 A2/A3 path, the scales as token-major pools
+    ``(L, n_tok, Hk)`` or heads-major per-slot rows ``(L, B, Hk, S)``.
+    Routing between compiled / interpret / ref is governed by
+    :mod:`repro.kernels.dispatch`.
     """
     _validate(q, k, v, page_ids, pos, page_size, k_scale, v_scale)
     return dispatch.pallas_dispatch(
         "paged_flash_decode",
         _paged_flash_pallas_path,
         _paged_flash_ref_path,
-        q, k, v, page_ids, pos,
+        q, k, v, page_ids, pos, jnp.asarray(layer, jnp.int32),
         page_size=page_size,
         k_scale=k_scale,
         v_scale=v_scale,

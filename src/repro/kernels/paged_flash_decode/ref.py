@@ -1,7 +1,8 @@
 """Pure-jnp oracle for the page-table-aware flash decode kernel.
 
-Operates on the RAW paged operands — token-major pools ``(n_tok, Hk, ...)``
-plus a ``(B, pages_per_slot)`` page table — and reproduces the serving
+Operates on the RAW paged operands — token-major pools ``(L, n_tok,
+Hk*D)`` of every layer, a layer index and a ``(B, pages_per_slot)`` page
+table — and reproduces the serving
 engine's decode attend (``engine._cache_attend`` at Q=1) op for op over the
 gathered heads-major view.  That makes this file the single numerical
 contract both the Pallas body and the engine's jnp path are tested against:
@@ -33,29 +34,35 @@ def gather_phys(page_ids: jax.Array, page_size: int, seq_len: int) -> jax.Array:
 
 def paged_flash_decode_ref(
     q: jax.Array,  # (B, Hk, g, D) f32 grouped decode query
-    k: jax.Array,  # (n_tok, Hk, D) bf16/f32, or int8 with k_scale
-    v: jax.Array,  # (n_tok, Hk, D) bf16/f32, or int8 with v_scale
+    k: jax.Array,  # (L, n_tok, Hk*D) bf16/f32, or int8 with k_scale
+    v: jax.Array,  # (L, n_tok, Hk*D) bf16/f32, or int8 with v_scale
     page_ids: jax.Array,  # (B, pages_per_slot) int32, -1 = unmapped
     pos: jax.Array,  # (B,) int32 — keys at logical s <= pos[b] are valid
+    layer: jax.Array,  # () int32 — the layer of the stack to attend
     *,
     page_size: int,
-    k_scale: Optional[jax.Array] = None,  # (n_tok, Hk) f32 (int8 format)
-    v_scale: Optional[jax.Array] = None,  # (n_tok, Hk) f32 (int8 format)
+    k_scale: Optional[jax.Array] = None,  # int8: (L, n_tok, Hk) or (L, B, Hk, S)
+    v_scale: Optional[jax.Array] = None,  # int8: (L, n_tok, Hk) or (L, B, Hk, S)
 ) -> jax.Array:
-    """Single-token paged attend -> f32 ``(B, Hk, g, D)``.
+    """Single-token paged attend of layer ``layer`` -> f32 ``(B, Hk, g, D)``.
 
     The attended sequence length is ``pages_per_slot * page_size`` (every
     lane a page table row can address); lanes past ``pos[b]`` are masked to
     ``NEG_INF`` exactly like the engine's position mask, so garbage rows
-    behind unmapped pages can never contribute probability mass.
+    behind unmapped pages can never contribute probability mass.  Scales
+    are token-major pools, translated through the page table like K/V, or
+    heads-major per-slot rows indexed by logical position.
     """
     B, Hk, g, D = q.shape
     S = page_ids.shape[1] * page_size
     scale = D**-0.5
     phys = gather_phys(page_ids, page_size, S)  # (B, S)
 
-    def view(pool):  # (n_tok, Hk, ...) -> heads-major (B, Hk, S, ...)
-        return jnp.moveaxis(pool[phys], 2, 1)
+    def rows(pool):  # layer of a token-major pool -> per-slot (B, S, ...)
+        return pool[layer][phys]
+
+    def view(kv):  # (L, n_tok, Hk*D) -> heads-major (B, Hk, S, D)
+        return jnp.moveaxis(rows(kv).reshape(B, S, Hk, D), 2, 1)
 
     qg = q[:, :, :, None, :].astype(jnp.float32)  # (B, Hk, g, Q=1, D)
     valid = (jnp.arange(S)[None, :] <= pos[:, None])[:, None]  # (B, Q=1, S)
@@ -76,8 +83,10 @@ def paged_flash_decode_ref(
     logits_i = jnp.einsum(
         "bhgqd,bhsd->bhgqs", q_q, view(k), preferred_element_type=jnp.int32
     )
-    ks = jnp.moveaxis(k_scale[phys], 2, 1)  # (B, Hk, S)
-    vs = jnp.moveaxis(v_scale[phys], 2, 1)
+    def scale_rows(sc):  # -> (B, Hk, S)
+        return jnp.moveaxis(rows(sc), 2, 1) if sc.ndim == 3 else sc[layer]
+
+    ks, vs = scale_rows(k_scale), scale_rows(v_scale)
     logits = (
         logits_i.astype(jnp.float32)
         * q_scale

@@ -468,8 +468,8 @@ def _attn_decode_layer(p, cfg, layout, cache, x, pos, layer_idx, theta, rules,
         else:
             valid = (abs_pos >= 0) & (pos_c - abs_pos < w) & (abs_pos <= pos_c)
         fmt_l = "int8" if "k_scale" in store else "bf16"
-        out = _decode_attend(q[:, 0], kvc.slot_entry(store, li), valid, cfg,
-                             fmt_l)
+        entry = kvc.slot_entry(store, li, heads=cfg.num_kv_heads)
+        out = _decode_attend(q[:, 0], entry, valid, cfg, fmt_l)
     else:
         gi = layout.global_layers.index(layer_idx)
         valid = jnp.arange(layout.max_seq)[None, :] <= pos_c  # (B, S)
@@ -493,7 +493,8 @@ def _attn_decode_layer(p, cfg, layout, cache, x, pos, layer_idx, theta, rules,
                     layout, rules,
                 )
             elif out is None:
-                entry = kvc.paged_entry(cache["global"], gi, phys)
+                entry = kvc.paged_entry(cache["global"], gi, phys,
+                                        heads=cfg.num_kv_heads)
                 # pin the gathered view as a materialization point: without
                 # it XLA fuses the page gather INTO the attend, and the
                 # fused lowering's float reduction order shifts once any
@@ -510,7 +511,8 @@ def _attn_decode_layer(p, cfg, layout, cache, x, pos, layer_idx, theta, rules,
                     decode_mode,
                 )
             if out is None:
-                entry = kvc.slot_entry(cache["global"], gi)
+                entry = kvc.slot_entry(cache["global"], gi,
+                                       heads=cfg.num_kv_heads)
                 if fmt == "bgpp":
                     out = _bgpp_decode_attend(q[:, 0], entry, valid, cfg)
                 else:
@@ -863,7 +865,8 @@ def _chunk_attend_local(cfg, layout, store, li, slot, q, k, v, qpos, offset,
     g = Hq // Hk
     W = layout.local_window
 
-    ring = {n: a[0] for n, a in kvc.slot_entry(store, li, slot).items()}
+    ring = {n: a[0] for n, a in
+            kvc.slot_entry(store, li, slot, heads=Hk).items()}
     if "k_scale" in ring:  # heads-major (Hk, W, ...)
         kr = ring["k"].astype(jnp.float32) * ring["k_scale"][..., None]
         vr = ring["v"].astype(jnp.float32) * ring["v_scale"][..., None]
@@ -942,7 +945,8 @@ def _attn_chunk_layer(p, cfg, layout, cache, x, slot, offset, length,
                 length=length, page_table=cache["page_table"],
                 **_paged_kw(layout),
             )
-            view = kvc.paged_entry(cache["global"], gi, phys)
+            view = kvc.paged_entry(cache["global"], gi, phys,
+                                   heads=cfg.num_kv_heads)
             # same materialization pin as the decode layer: stop the page
             # gather fusing into the chunk attend (sharding-stable lowering)
             view = jax.lax.optimization_barrier(view)
@@ -951,7 +955,8 @@ def _attn_chunk_layer(p, cfg, layout, cache, x, slot, offset, length,
                 cache["global"], gi, k, v, slot=slot, offset=offset,
                 length=length,
             )
-            view = kvc.slot_entry(cache["global"], gi, slot)
+            view = kvc.slot_entry(cache["global"], gi, slot,
+                                  heads=cfg.num_kv_heads)
         S = layout.max_seq
         valid = (jnp.arange(S)[None, :] <= qpos[:, None])[None]  # (1, C, S)
         if fmt == "bgpp":

@@ -4,16 +4,24 @@ paged-attention kernel families.
 The engine's decode hot path (``engine._attn_decode_layer``, global
 branch) has two kernel-backed formats:
 
-* ``paged_flash_decode`` — bf16 / int8 dense attend.  The paged layout
-  passes its token-major pools and the ``(B, S_max/page_size)`` page
-  table STRAIGHT into the kernel (the BlockSpec index map gathers
-  physical pages; ``kv_cache.paged_entry``'s contiguous per-slot view is
-  never built).  The slot layout's token-major K/V stacks are the same
-  rows with a batch dim: reshaped into pools and addressed by an identity
-  page table (one page per slot), so one kernel serves both.
+* ``paged_flash_decode`` — bf16 / int8 dense attend.  It takes the WHOLE
+  stacked cache and the layer index, and reads the layer in place: no
+  step program copies one layer out of the stack for the custom call.
+  The paged layout passes its ``(L, n_tok, Hk*D)`` pools and the
+  ``(B, S_max/page_size)`` page table straight in (the BlockSpec index
+  map gathers physical pages; ``kv_cache.paged_entry``'s contiguous
+  per-slot view is never built).  The slot layout's token-row
+  ``(L, B, S, Hk*D)`` stacks are the same rows with a batch dim: their
+  leading dims merge into ``(L, B*S, Hk*D)`` for free, and an identity
+  page table addresses each slot as ``S/P`` sub-pages of ``P`` rows, ``P``
+  from the shapes (``slot_page_rows``), so one kernel serves both.  The
+  slot stacks' int8 scales ``(L, B, Hk, S)`` are read in place; the paged
+  pools' token-major scales are gathered per slot for the one layer.
 * ``bgpp_paged_attend`` — the fused two-phase BGPP decode (plane scan,
-  progressive top-k, exact int8 attend over the survivors) in one launch,
-  fed the same way.
+  progressive top-k, exact int8 attend over the survivors) in one launch.
+  It still takes one layer's token-major pool leaves, so a slot-layout
+  bgpp store is transposed into them first (``_pool_views``); no
+  benchmark cell runs it.
 
 Mode resolution happens ONCE at ``make_serve_step`` build time
 (:func:`resolve`): the ``decode_kernel`` config knob (or the
@@ -43,6 +51,9 @@ from repro.distributed import sharding as sh
 from repro.kernels import MODE_COMPILED, MODE_INTERPRET
 from repro.kernels.bgpp_paged_attend import bgpp_paged_attend
 from repro.kernels.paged_flash_decode import paged_flash_decode
+from repro.kernels.paged_flash_decode.kernel import (
+    slot_page_rows, slot_table,
+)
 from repro.serving import kv_cache as kvc
 
 Tree = Dict[str, Any]
@@ -108,31 +119,25 @@ def validate(cfg, layout) -> None:
             )
 
 
-def _pool_views(store: Tree, gi: int, fmt: str, slot_layout: bool) -> Tree:
-    """Layer ``gi``'s token-major pool leaves.
+def _pool_views(store: Tree, gi: int, slot_layout: bool) -> Tree:
+    """Layer ``gi`` of a bgpp store as token-major pool leaves.
 
     Paged stores already hold token-major pools — this just indexes the
     layer.  Slot stores become ``(B*S, Hk, ...)`` pools whose row
     ``b*S + s`` is slot ``b``'s logical position ``s`` (an identity page
-    table of one ``S``-row page per slot addresses them), so both layouts
-    feed one kernel: token-major K/V bodies ``(B, S, Hk, D)`` by a reshape,
-    the heads-major leaves (scales, the bgpp store) by a transpose.
+    table of one ``S``-row page per slot addresses them): their leaves are
+    heads-major, so each is transposed.  Only ``bgpp_paged_attend`` reads
+    these copies; ``paged_flash_decode`` reads the stacked cache in place.
     """
     if not slot_layout:
-        if fmt == "bgpp":
-            return {n: store[n][gi] for n in
-                    ("k_planes", "k_sign", "k_scale", "v", "v_scale")}
-        names = ("k", "v") if fmt == "bf16" else ("k", "v", "k_scale", "v_scale")
-        return {n: store[n][gi] for n in names}
+        return {n: store[n][gi] for n in
+                ("k_planes", "k_sign", "k_scale", "v", "v_scale")}
     out: Tree = {}
     for n in store:
         a = store[n][gi]
         if n == "k_planes":  # (NBITS, B, Hk, S, D/8) -> (NBITS, B*S, Hk, D/8)
             nb, B, Hk, S, Dp = a.shape
             out[n] = a.transpose(0, 1, 3, 2, 4).reshape(nb, B * S, Hk, Dp)
-        elif kvc.token_major(store, n):  # (B, S, Hk, D) -> (B*S, Hk, D)
-            B, S, Hk, D = a.shape
-            out[n] = a.reshape(B * S, Hk, D)
         elif a.ndim == 4:  # (B, Hk, S, D) -> (B*S, Hk, D)
             B, Hk, S, D = a.shape
             out[n] = a.transpose(0, 2, 1, 3).reshape(B * S, Hk, D)
@@ -142,13 +147,15 @@ def _pool_views(store: Tree, gi: int, fmt: str, slot_layout: bool) -> Tree:
     return out
 
 
-def _attend_local(q1, pool: Tree, pos, table, cfg, layout, mode: str):
+def _attend_local(q1, store: Tree, gi: int, pos, table, cfg, layout,
+                  mode: str):
     """Run the kernel family on device-local operands -> ``(B, Hq, Dh)``.
 
-    ``table`` is the page table — for the slot layout the caller passes
-    ``None`` and each slot's ``S`` rows of the pool-ified stack form one
-    page (an identity table built here from the LOCAL batch size), so the
-    same body serves the ``shard_map``-wrapped and unsharded calls.
+    ``store`` is the device's shard of ``cache["global"]``.  ``table`` is
+    the page table — for the slot layout the caller passes ``None`` and an
+    identity table over the stack's ``(B*S)`` rows is built here from the
+    LOCAL batch size, so the same body serves the ``shard_map``-wrapped and
+    unsharded calls.
     """
     B, Hq, Dh = q1.shape
     g = cfg.num_heads // cfg.num_kv_heads  # ratio: shard-invariant
@@ -156,13 +163,13 @@ def _attend_local(q1, pool: Tree, pos, table, cfg, layout, mode: str):
     qg = q1.reshape(B, Hk, g, Dh).astype(jnp.float32)
     fmt = layout.kv_format
     S = layout.max_seq
-    if table is None:  # slot: slot b's rows [b*S, (b+1)*S) are its one page
-        table = jnp.arange(B, dtype=jnp.int32)[:, None]
-        page_size = S
-    else:
-        page_size = layout.page_size
 
     if fmt == "bgpp":
+        pool = _pool_views(store, gi, table is None)
+        page_size = layout.page_size
+        if table is None:  # slot: slot b's rows [b*S, (b+1)*S) are its page
+            table = jnp.arange(B, dtype=jnp.int32)[:, None]
+            page_size = S
         rounds, k_max, survivors = kvc.bgpp_decode_plan(S, cfg)
         out = bgpp_paged_attend(
             qg, pool["k_planes"], pool["k_sign"], pool["k_scale"],
@@ -170,12 +177,22 @@ def _attend_local(q1, pool: Tree, pos, table, cfg, layout, mode: str):
             rounds=rounds, k_max=k_max, survivors=survivors, mode=mode,
         )
     else:
+        k, v = store["k"], store["v"]
+        page_size = layout.page_size
+        if table is None:  # slot: (L, B, S, Hk*D) -> (L, B*S, Hk*D), free
+            L, _, _, width = k.shape
+            page_size = slot_page_rows(S, width, k.dtype.itemsize)
+            k = k.reshape(L, B * S, width)
+            v = v.reshape(L, B * S, width)
+            table = slot_table(B, S, page_size)
+        # int8 scales as stored: the slot stacks' (L, B, Hk, S) rows are
+        # read in place, the paged pools' (L, n_tok, Hk) gathered per slot
         scales = (
             {} if fmt == "bf16"
-            else {"k_scale": pool["k_scale"], "v_scale": pool["v_scale"]}
+            else {"k_scale": store["k_scale"], "v_scale": store["v_scale"]}
         )
         out = paged_flash_decode(
-            qg, pool["k"], pool["v"], table, pos,
+            qg, k, v, table, pos, gi,
             page_size=page_size, mode=mode, **scales,
         )
     # (B, Hk, g, Dh) -> (B, Hq, Dh): same axis order as the engine's
@@ -196,7 +213,6 @@ def decode_attend(q1, store: Tree, gi: int, pos, cfg, layout, rules,
     """
     if mode == "jnp":
         return None
-    fmt = layout.kv_format
     slot = layout.layout != "paged"
     table = None if slot else page_table
     pos = pos.astype(jnp.int32)
@@ -204,16 +220,14 @@ def decode_attend(q1, store: Tree, gi: int, pos, cfg, layout, rules,
     mesh = getattr(rules, "mesh", None)
     m = dict(mesh.shape).get(rules.model_axis, 1) if mesh is not None else 1
     if mesh is None or m <= 1:
-        pool = _pool_views(store, gi, fmt, slot)
-        return _attend_local(q1, pool, pos, table, cfg, layout, mode)
+        return _attend_local(q1, store, gi, pos, table, cfg, layout, mode)
     if cfg.num_kv_heads % m or cfg.num_heads % m:
         return None  # heads don't shard: engine jnp fallback (replicated)
     if slot and (getattr(rules, "seq_shard", False) or getattr(rules, "sp", False)):
         return None  # seq-sharded slot stacks break the identity pool maps
     def run(q_, store_, pos_, table_):
-        pool = _pool_views(store_, gi, fmt, slot)
         t = table_ if not slot else None
-        return _attend_local(q_, pool, pos_, t, cfg, layout, mode)
+        return _attend_local(q_, store_, gi, pos_, t, cfg, layout, mode)
 
     spec = lambda axes, x: rules.spec_for_shape(mesh, axes, x.shape)
     store_spec = jax.tree.map(

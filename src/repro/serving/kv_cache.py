@@ -28,8 +28,8 @@ Two physical layouts for the *global* stacks (``CacheLayout.layout``):
           the heads-major view the jnp attends read of a slot stack
           (:func:`slot_entry`), so attention consumes bit-identical values
           (verified by the serving fuzz oracle).  The slot stacks' bf16/int8
-          K/V bodies share the pools' token-major row order (a slot row is
-          one ``S``-row page).  Page lifecycle (free lists, refcounts,
+          K/V bodies share the pools' token rows ``(..., Hk*D)`` (a slot
+          row is one ``S``-row page).  Page lifecycle (free lists, refcounts,
           prefix reuse) is host-side: :mod:`repro.serving.paging`.  Local
           ring stacks, mamba state, and cross memory stay slot-major —
           rings are already fixed-width per-slot pages by construction.
@@ -141,20 +141,21 @@ def layout_for(cfg, batch: int, max_seq: int, kv_format: str = "int8",
 
 
 def _kv_stack(n_layers, B, S, Hk, Dh, kv_format, dtype):
-    # bf16/int8 k/v bodies are token-major (B, S, Hk, D), the paged pools'
-    # row order: one decode token's (Hk, D) row is one contiguous tile, so
-    # the step programs scatter it in place instead of relaying the whole
-    # stack around the write.  Scales stay lane-dense (B, Hk, S) rows; the
-    # bgpp store stays heads-major throughout (see token_major)
+    # bf16/int8 k/v bodies are token rows (B, S, Hk*D), the paged pools'
+    # row order: the step programs scatter one decode token's row in place
+    # instead of relaying the whole stack around the write, and a head's
+    # rows are a lane block [h*D, (h+1)*D) the decode kernel DMAs as
+    # stored.  Scales stay lane-dense (B, Hk, S) rows; the bgpp store stays
+    # heads-major throughout (see token_major)
     p: Tree = {}
     if n_layers == 0:
         return p
     if kv_format == "bf16":
-        p["k"] = jnp.zeros((n_layers, B, S, Hk, Dh), dtype)
-        p["v"] = jnp.zeros((n_layers, B, S, Hk, Dh), dtype)
+        p["k"] = jnp.zeros((n_layers, B, S, Hk * Dh), dtype)
+        p["v"] = jnp.zeros((n_layers, B, S, Hk * Dh), dtype)
     elif kv_format == "int8":
         for n in ("k", "v"):
-            p[n] = jnp.zeros((n_layers, B, S, Hk, Dh), jnp.int8)
+            p[n] = jnp.zeros((n_layers, B, S, Hk * Dh), jnp.int8)
             p[f"{n}_scale"] = jnp.zeros((n_layers, B, Hk, S), jnp.float32)
     elif kv_format == "bgpp":
         assert Dh % 8 == 0
@@ -169,20 +170,21 @@ def _kv_stack(n_layers, B, S, Hk, Dh, kv_format, dtype):
 
 
 def _kv_pool(n_layers, n_tok, Hk, Dh, kv_format, dtype):
-    """Paged pool: token-major ``(L, n_tok, Hk, ...)`` per-layer stores with
-    NO batch dim — ``n_tok = num_pages * page_size`` physical rows shared by
-    every slot through the page table.  Token-major (the slot stacks' K/V
-    body order too) lets page gathers/scatters address one contiguous row
-    axis; reads restore the heads-major view (:func:`paged_entry`)."""
+    """Paged pool: token-major ``(L, n_tok, ...)`` per-layer stores with NO
+    batch dim — ``n_tok = num_pages * page_size`` physical rows shared by
+    every slot through the page table; bf16/int8 K/V rows are ``Hk*D``
+    wide, as in the slot stacks.  Token-major lets page gathers/scatters
+    address one contiguous row axis; reads restore the heads-major view
+    (:func:`paged_entry`)."""
     p: Tree = {}
     if n_layers == 0:
         return p
     if kv_format == "bf16":
-        p["k"] = jnp.zeros((n_layers, n_tok, Hk, Dh), dtype)
-        p["v"] = jnp.zeros((n_layers, n_tok, Hk, Dh), dtype)
+        p["k"] = jnp.zeros((n_layers, n_tok, Hk * Dh), dtype)
+        p["v"] = jnp.zeros((n_layers, n_tok, Hk * Dh), dtype)
     elif kv_format == "int8":
         for n in ("k", "v"):
-            p[n] = jnp.zeros((n_layers, n_tok, Hk, Dh), jnp.int8)
+            p[n] = jnp.zeros((n_layers, n_tok, Hk * Dh), jnp.int8)
             p[f"{n}_scale"] = jnp.zeros((n_layers, n_tok, Hk), jnp.float32)
     elif kv_format == "bgpp":
         assert Dh % 8 == 0
@@ -203,12 +205,12 @@ def _kv_pool_specs(kv_format):
     # stays replicated: every device needs every slot's logical→physical
     # map to gather its own head shard of any row.
     if kv_format == "bf16":
-        ax = (sh.LAYERS, None, sh.KV_HEADS, None)
+        ax = (sh.LAYERS, None, sh.KV_HEADS)
         return {"k": ax, "v": ax}
     if kv_format == "int8":
         s = {}
         for n in ("k", "v"):
-            s[n] = (sh.LAYERS, None, sh.KV_HEADS, None)
+            s[n] = (sh.LAYERS, None, sh.KV_HEADS)
             s[f"{n}_scale"] = (sh.LAYERS, None, sh.KV_HEADS)
         return s
     if kv_format == "bgpp":
@@ -223,13 +225,17 @@ def _kv_pool_specs(kv_format):
 
 
 def _kv_stack_specs(kv_format):
+    # bf16/int8 token rows (..., Hk*D) shard over the model axis as their
+    # heads do: a device holds whole heads whenever Hk divides the axis
+    # (the decode kernel's shard_map needs that; where it does not, the
+    # engine takes its jnp attend)
     if kv_format == "bf16":
-        ax = (sh.LAYERS, sh.BATCH, sh.SEQ, sh.KV_HEADS, None)
+        ax = (sh.LAYERS, sh.BATCH, sh.SEQ, sh.KV_HEADS)
         return {"k": ax, "v": ax}
     if kv_format == "int8":
         s = {}
         for n in ("k", "v"):
-            s[n] = (sh.LAYERS, sh.BATCH, sh.SEQ, sh.KV_HEADS, None)
+            s[n] = (sh.LAYERS, sh.BATCH, sh.SEQ, sh.KV_HEADS)
             s[f"{n}_scale"] = (sh.LAYERS, sh.BATCH, sh.KV_HEADS, sh.SEQ)
         return s
     if kv_format == "bgpp":
@@ -399,13 +405,13 @@ def bitplanes_to_k(planes: jax.Array, sign: jax.Array) -> jax.Array:
 
 
 # --------------------------------------------------------------------------
-# slot-stack addressing — token-major bodies, heads-major scales
+# slot-stack addressing — token-row bodies, heads-major scales
 # --------------------------------------------------------------------------
 
 
 def token_major(store: Tree, name: str) -> bool:
     """Whether leaf ``name`` of a slot stack is token-major
-    ``(L, B, S, Hk, D)``: the bf16/int8 ``k``/``v`` bodies.  Their scales
+    ``(L, B, S, Hk*D)``: the bf16/int8 ``k``/``v`` bodies.  Their scales
     are lane-dense ``(L, B, Hk, S)`` rows, and a bgpp store (``k_planes``
     present) stays heads-major in every leaf — its plane layout is the one
     the bgpp decode reads."""
@@ -416,17 +422,20 @@ def _seq_index(store: Tree, name: str, idx, b, t) -> tuple:
     """Index of seq positions ``t`` of batch row(s) ``b`` in layer(s)
     ``idx`` of slot-stack leaf ``name`` (not ``k_planes``).  With ``b`` and
     ``t`` both arrays, or ``b`` a slice, both forms select the same
-    ``(..., Hk[, D])`` target shape (advanced dims first)."""
+    ``(..., Hk*D)`` or ``(..., Hk)`` target shape (advanced dims first)."""
     if token_major(store, name):
         return (idx, b, t)
     return (idx, b, slice(None), t)
 
 
-def slot_entry(store: Tree, idx, slot=None) -> Tree:
+def slot_entry(store: Tree, idx, slot=None, *,
+               heads: Optional[int] = None) -> Tree:
     """Layer ``idx`` of a slot stack as the heads-major entry the engine's
     jnp attends read: ``(B, Hk, S, ...)`` every leaf (``k_planes``
     ``(NBITS, B, Hk, S, D/8)``); ``slot`` (traced ok) keeps one batch row as
-    a batch of one; ring ``abs_pos`` is left out.
+    a batch of one; ring ``abs_pos`` is left out.  ``heads`` is ``Hk``, the
+    count the token rows of a bf16/int8 body split into (a bgpp store
+    needs none).
 
     Token-major bodies are swapped at the read and the swap pinned as a
     materialization point, as :func:`paged_entry`'s gathered view is in the
@@ -447,20 +456,31 @@ def slot_entry(store: Tree, idx, slot=None) -> Tree:
         else:
             a = a[idx, slot][None]
         if token_major(store, n):
+            a = _split_heads(a, heads)
             a = jax.lax.optimization_barrier(jnp.swapaxes(a, 1, 2))
         out[n] = a
     return out
 
 
+def _split_heads(rows: jax.Array, heads: Optional[int]) -> jax.Array:
+    """Token rows ``(..., Hk*D)`` -> ``(..., Hk, D)``."""
+    if heads is None:
+        raise ValueError("a bf16/int8 store's token rows need heads=Hk to "
+                         "split into (Hk, D)")
+    return rows.reshape(rows.shape[:-1] + (heads, -1))
+
+
 def _kv_rows(store: Tree, k: jax.Array, v: jax.Array) -> Tree:
-    """The leaves a bf16 or int8 store keeps for K/V rows ``(..., Hk, Dh)``:
-    values cast to the store's dtype, or int8 values ``(..., Hk, Dh)`` with
+    """The leaves a bf16 or int8 store keeps for K/V ``(..., Hk, Dh)``:
+    token rows ``(..., Hk*Dh)`` cast to the store's dtype, or int8 rows with
     f32 scales ``(..., Hk)``."""
+    rows = lambda x: x.reshape(x.shape[:-2] + (-1,))
     if "k_scale" in store:
         kq, ks = quantize_kv(k)
         vq, vs = quantize_kv(v)
-        return {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
-    return {"k": k.astype(store["k"].dtype), "v": v.astype(store["v"].dtype)}
+        return {"k": rows(kq), "v": rows(vq), "k_scale": ks, "v_scale": vs}
+    return {"k": rows(k).astype(store["k"].dtype),
+            "v": rows(v).astype(store["v"].dtype)}
 
 
 # --------------------------------------------------------------------------
@@ -507,20 +527,24 @@ def _phys_write(page_table: jax.Array, tpos: jax.Array, page_size: int,
     return jnp.where(ok, pid * page_size + tpos % page_size, OOB_INDEX)
 
 
-def paged_entry(store: Tree, idx, phys: jax.Array) -> Tree:
+def paged_entry(store: Tree, idx, phys: jax.Array, *,
+                heads: Optional[int] = None) -> Tree:
     """Gather layer ``idx`` of a paged pool into the heads-major entry
     :func:`slot_entry` gives of a slot stack: phys ``(B, S)`` -> entries
-    ``(B, Hk, S, ...)`` (and ``(NBITS, B, Hk, S, D/8)`` for bgpp planes).
-    The gathered values are exactly the dense row's values, which is what
-    keeps paged attention bit-identical to the slot layout."""
+    ``(B, Hk, S, ...)`` (and ``(NBITS, B, Hk, S, D/8)`` for bgpp planes);
+    ``heads`` as in :func:`slot_entry`.  The gathered values are exactly
+    the dense row's values, which is what keeps paged attention
+    bit-identical to the slot layout."""
     out: Tree = {}
     for n, a in store.items():
         if n == "k_planes":
             g = a[idx][:, phys]  # (NBITS, B, S, Hk, D/8)
             out[n] = jnp.moveaxis(g, 3, 2)
-        else:
-            g = a[idx][phys]  # (B, S, Hk, ...)
-            out[n] = jnp.moveaxis(g, 2, 1)
+            continue
+        g = a[idx][phys]  # (B, S, Hk, ...), or (B, S, Hk*D) token rows
+        if token_major(store, n):
+            g = _split_heads(g, heads)
+        out[n] = jnp.moveaxis(g, 2, 1)
     return out
 
 
@@ -948,8 +972,16 @@ def write_token(store: Tree, idx: int, k: jax.Array, v: jax.Array,
         store["v_scale"] = store["v_scale"].at[idx, bidx, :, tpos].set(vs[:, 0])
     else:  # int8 / bf16: one (Hk, D) row per slot, (Hk,) scales
         for n, x in _kv_rows(store, k, v).items():
-            store[n] = store[n].at[_seq_index(store, n, idx, bidx, tpos)].set(
-                x[:, 0])
+            if token_major(store, n):
+                store[n] = store[n].at[idx, bidx, tpos].set(x[:, 0])
+                continue
+            # a (B, Hk, S) scale row takes its lane by a select over the
+            # layer: XLA lays a scatter into it out token-major, and the
+            # decode kernel reads the stack as stored, so a scatter would
+            # relay the whole stack around every layer's attend
+            lane = jnp.arange(store[n].shape[-1]) == tpos[:, None, None]
+            store[n] = store[n].at[idx].set(
+                jnp.where(lane, x[:, 0, :, None], store[n][idx]))
     return store
 
 

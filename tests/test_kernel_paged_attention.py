@@ -11,7 +11,8 @@ jitted wrappers).
 Swept: page-boundary position spans, deliberately shuffled (non-identity)
 page tables / phys maps so logical->physical translation is actually
 exercised, cache fills below / at / above the bgpp keep budget, and GQA
-ratios including Hq == Hk.  A second class checks the kernel family
+ratios including Hq == Hk.  ``paged_flash_decode`` reads a stacked cache
+whose layers hold different contents, so a wrong layer index fails.  A second class checks the kernel family
 against the ENGINE's legacy jnp attend on real caches (the contract
 ``serving.kernel_decode`` relies on when routing the serve_step), and a
 third pins the actionable build-time validation errors.
@@ -30,6 +31,9 @@ from repro.configs import get_config
 from repro.configs.base import MCBPOptions
 from repro.kernels.bgpp_paged_attend import bgpp_paged_attend
 from repro.kernels.paged_flash_decode import paged_flash_decode
+from repro.kernels.paged_flash_decode.kernel import (
+    slot_page_rows, slot_table,
+)
 from repro.serving import engine, kernel_decode, kv_cache as kvc
 
 jax.config.update("jax_platform_name", "cpu")
@@ -46,14 +50,28 @@ def _plan(S, rounds=4, keep=0.25):
     return rounds, k_max, survivors
 
 
-def _dense_pools(rng, n_tok, Hk, fmt):
-    kf = jnp.asarray(rng.normal(size=(n_tok, Hk, D)), jnp.float32)
-    vf = jnp.asarray(rng.normal(size=(n_tok, Hk, D)), jnp.float32)
+LAYERS = 3  # stacked operands: a middle and a last layer to pick
+
+
+def _dense_pools(rng, n_tok, Hk, fmt, layers=LAYERS):
+    """Token-row stacks ``(L, n_tok, Hk*D)``, every layer drawn anew, with
+    int8 scales as token-major pools ``(L, n_tok, Hk)``."""
+    kf = jnp.asarray(rng.normal(size=(layers, n_tok, Hk, D)), jnp.float32)
+    vf = jnp.asarray(rng.normal(size=(layers, n_tok, Hk, D)), jnp.float32)
+    rows = lambda x: x.reshape(layers, n_tok, Hk * D)
     if fmt == "bf16":
-        return kf.astype(jnp.bfloat16), vf.astype(jnp.bfloat16), {}
+        return (rows(kf).astype(jnp.bfloat16), rows(vf).astype(jnp.bfloat16),
+                {})
     k_q, ks = kvc.quantize_kv(kf)
     v_q, vs = kvc.quantize_kv(vf)
-    return k_q, v_q, {"k_scale": ks, "v_scale": vs}
+    return rows(k_q), rows(v_q), {"k_scale": ks, "v_scale": vs}
+
+
+def _slot_scales(scales, B, S):
+    """Token-major scale pools ``(L, B*S, Hk)`` as the slot stacks keep
+    them: heads-major per-slot rows ``(L, B, Hk, S)``."""
+    return {n: jnp.swapaxes(a.reshape(a.shape[0], B, S, -1), 2, 3)
+            for n, a in scales.items()}
 
 
 def _bgpp_pools(rng, n_tok, Hk):
@@ -87,10 +105,11 @@ class TestPagedFlashDecodeParity:
         pos = jnp.asarray([pos_val, max(0, pos_val - 1)], jnp.int32)
 
         out_i = paged_flash_decode(
-            q, k, v, table, pos, page_size=PAGE, mode="interpret", **scales
+            q, k, v, table, pos, 1, page_size=PAGE, mode="interpret",
+            **scales
         )
         out_r = paged_flash_decode(
-            q, k, v, table, pos, page_size=PAGE, mode="ref", **scales
+            q, k, v, table, pos, 1, page_size=PAGE, mode="ref", **scales
         )
         assert out_i.shape == (B, Hk, g, D)
         assert np.array_equal(np.asarray(out_i), np.asarray(out_r)), (
@@ -110,14 +129,82 @@ class TestPagedFlashDecodeParity:
         q = jnp.asarray(rng.normal(size=(B, Hk, g, D)), jnp.float32)
         pos = jnp.asarray([PAGE - 1], jnp.int32)  # only page 1's lanes valid
         base = paged_flash_decode(
-            q, k, v, table, pos, page_size=PAGE, mode="interpret"
+            q, k, v, table, pos, 1, page_size=PAGE, mode="interpret"
         )
-        k_p = k.at[2 * PAGE:].set(jnp.asarray(1e4, k.dtype))
-        v_p = v.at[2 * PAGE:].set(jnp.asarray(1e4, v.dtype))
+        k_p = k.at[:, 2 * PAGE:].set(jnp.asarray(1e4, k.dtype))
+        v_p = v.at[:, 2 * PAGE:].set(jnp.asarray(1e4, v.dtype))
         poisoned = paged_flash_decode(
-            q, k_p, v_p, table, pos, page_size=PAGE, mode="interpret"
+            q, k_p, v_p, table, pos, 1, page_size=PAGE, mode="interpret"
         )
         assert np.array_equal(np.asarray(base), np.asarray(poisoned))
+
+    @pytest.mark.parametrize("fmt", ["bf16", "int8"])
+    @pytest.mark.parametrize("layout", ["paged", "slot"])
+    @pytest.mark.parametrize("layer", [1, LAYERS - 1])
+    def test_reads_the_named_layer(self, fmt, layout, layer):
+        """Layer ``layer`` of a stack whose layers differ attends exactly
+        as that layer alone does, and no other layer's output matches it.
+        The slot form reads the ``(L, B*S, Hk*D)`` merge of a slot stack in
+        sub-pages (S/P = 4) with its scales as heads-major per-slot rows."""
+        B, Hk, g, S = 2, 2, 3, 16
+        rng = np.random.default_rng(10 * layer + (fmt == "int8"))
+        if layout == "slot":
+            k, v, scales = _dense_pools(rng, B * S, Hk, fmt)
+            scales = _slot_scales(scales, B, S)
+            page, table = S // 4, slot_table(B, S, S // 4)
+        else:
+            page, pp = PAGE, S // PAGE
+            k, v, scales = _dense_pools(rng, (B * pp + 2) * PAGE, Hk, fmt)
+            table = jnp.asarray(rng.permutation(B * pp + 2)[:B * pp]
+                                .reshape(B, pp).astype(np.int32))
+        q = jnp.asarray(rng.normal(size=(B, Hk, g, D)), jnp.float32)
+        pos = jnp.asarray([S - 1, S // 2], jnp.int32)
+
+        def run(li, mode="interpret", only=False):
+            pick = (lambda a: a[li:li + 1]) if only else (lambda a: a)
+            return np.asarray(paged_flash_decode(
+                q, pick(k), pick(v), table, pos, 0 if only else li,
+                page_size=page, mode=mode,
+                **{n: pick(a) for n, a in scales.items()},
+            ))
+
+        out = run(layer)
+        assert np.array_equal(out, run(layer, mode="ref"))
+        assert np.array_equal(out, run(layer, only=True))
+        for other in range(LAYERS):
+            if other != layer:
+                assert not np.allclose(out, run(other)), (
+                    f"layer {other} reads like layer {layer}")
+
+    @pytest.mark.parametrize("fmt", ["bf16", "int8"])
+    def test_slot_sub_pages_match_one_page(self, fmt):
+        """A slot stack read in S/P sub-pages attends exactly as it does
+        read in one S-row page per slot."""
+        B, Hk, g, S = 2, 2, 3, 32
+        rng = np.random.default_rng(5 + (fmt == "int8"))
+        k, v, scales = _dense_pools(rng, B * S, Hk, fmt)
+        scales = _slot_scales(scales, B, S)
+        q = jnp.asarray(rng.normal(size=(B, Hk, g, D)), jnp.float32)
+        pos = jnp.asarray([S - 3, 6], jnp.int32)
+        outs = [np.asarray(paged_flash_decode(
+            q, k, v, slot_table(B, S, P), pos, 2, page_size=P,
+            mode="interpret", **scales)) for P in (S, S // 2, S // 4)]
+        assert all(np.array_equal(outs[0], o) for o in outs[1:])
+
+
+@pytest.mark.parametrize("S,width,itemsize,pages", [
+    (2048, 8 * 128, 1, 2),  # phi4-chat-int8: 20 slots x 2048, 8 kv heads
+    (4096, 8 * 128, 1, 4),  # a deepseek7b-longdoc-1x4 device: 8 of 32 heads
+    (2048, 8 * 128, 2, 4),  # bf16 rows of the same widths
+    (30, 2 * 16, 1, 1),  # ragged: no tile-multiple divisor, one page
+    (65536, 2 * 16, 1, 2),  # narrow rows: a long slot still splits
+])
+def test_slot_page_rows_split_by_shape(S, width, itemsize, pages):
+    """A slot's sub-page is the largest tile-multiple divisor of S within
+    the block budget, whole S when none is."""
+    P = slot_page_rows(S, width, itemsize)
+    assert S % P == 0 and S // P == pages, P
+    assert P == S or P % (32 // itemsize) == 0
 
 
 class TestBgppPagedAttendParity:
@@ -272,7 +359,8 @@ class TestEnginePathParity:
         valid = jnp.arange(S_MAX)[None, :] < s_ctx
         eng = jax.jit(
             lambda q_, st, ph: engine._decode_attend(
-                q_, kvc.paged_entry(st, 0, ph), valid, cfg, fmt
+                q_, kvc.paged_entry(st, 0, ph, heads=cfg.num_kv_heads),
+                valid, cfg, fmt
             )
         )(q, paged["global"], phys)
 
@@ -313,7 +401,8 @@ class TestEnginePathParity:
         else:
             eng = jax.jit(
                 lambda q_, st, ph: engine._decode_attend(
-                    q_, kvc.paged_entry(st, 0, ph), valid, cfg, fmt
+                    q_, kvc.paged_entry(st, 0, ph, heads=cfg.num_kv_heads),
+                valid, cfg, fmt
                 )
             )(q, paged["global"], phys)
         pos = jnp.full((B_ENG,), s_ctx - 1, jnp.int32)
@@ -379,7 +468,7 @@ class TestValidationErrors:
         with pytest.raises(ValueError, match="grouped \\(B, Hk, g, D\\)"):
             paged_flash_decode(
                 jnp.zeros((1, 4, D)), k, v,
-                jnp.zeros((1, 2), jnp.int32), jnp.zeros((1,), jnp.int32),
+                jnp.zeros((1, 2), jnp.int32), jnp.zeros((1,), jnp.int32), 0,
                 page_size=PAGE,
             )
 
@@ -389,7 +478,7 @@ class TestValidationErrors:
         with pytest.raises(ValueError, match="device-local head shard"):
             paged_flash_decode(
                 jnp.zeros((1, 4, 1, D)), k, v,
-                jnp.zeros((1, 2), jnp.int32), jnp.zeros((1,), jnp.int32),
+                jnp.zeros((1, 2), jnp.int32), jnp.zeros((1,), jnp.int32), 0,
                 page_size=PAGE,
             )
 
@@ -399,7 +488,7 @@ class TestValidationErrors:
         with pytest.raises(ValueError, match="whole number of pages"):
             paged_flash_decode(
                 jnp.zeros((1, 2, 1, D)), k, v,
-                jnp.zeros((1, 2), jnp.int32), jnp.zeros((1,), jnp.int32),
+                jnp.zeros((1, 2), jnp.int32), jnp.zeros((1,), jnp.int32), 0,
                 page_size=PAGE,
             )
 
@@ -409,7 +498,7 @@ class TestValidationErrors:
         with pytest.raises(ValueError, match="BOTH k_scale and v_scale"):
             paged_flash_decode(
                 jnp.zeros((1, 2, 1, D)), k, v,
-                jnp.zeros((1, 2), jnp.int32), jnp.zeros((1,), jnp.int32),
+                jnp.zeros((1, 2), jnp.int32), jnp.zeros((1,), jnp.int32), 0,
                 page_size=PAGE, k_scale=scales["k_scale"],
             )
 

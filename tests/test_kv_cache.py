@@ -98,8 +98,8 @@ def test_paged_writes_match_slot_layout(fmt):
 
     phys = kvc.phys_table(pt, ps, S)
     for gi in range(3):
-        view = kvc.paged_entry(paged, gi, phys)
-        entry = kvc.slot_entry(dense, gi)
+        view = kvc.paged_entry(paged, gi, phys, heads=Hk)
+        entry = kvc.slot_entry(dense, gi, heads=Hk)
         for n in dense:
             # dense layer entry and paged gather view share one shape:
             # (B, Hk, S, ...) — and must share every value

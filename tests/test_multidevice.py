@@ -99,14 +99,16 @@ perm = rng.permutation(lay.num_pages)[: 4 * lay.pages_per_slot]
 table = np.asarray(perm, np.int32).reshape(4, lay.pages_per_slot)
 pt = shd.replicated(table, rules)
 phys = kvc.phys_table(pt, lay.page_size, lay.max_seq)
-entry = jax.jit(lambda p, ph: kvc.paged_entry(p, 1, ph))(pool, phys)
+Hk = cfg.num_kv_heads
+entry = jax.jit(lambda p, ph: kvc.paged_entry(p, 1, ph, heads=Hk))(pool, phys)
 # host reference: logical position t of slot b lives in pool row
 # table[b, t // page] * page + t % page
 rows = (table[:, np.arange(lay.max_seq) // lay.page_size] * lay.page_size
         + np.arange(lay.max_seq) % lay.page_size)
 np.testing.assert_array_equal(np.asarray(phys), rows)
 for n in ("k", "v"):
-    want = np.asarray(pool[n])[1][rows]          # (B, S, Hk, D)
+    want = np.asarray(pool[n])[1][rows]          # (B, S, Hk*D) token rows
+    want = want.reshape(want.shape[:2] + (Hk, -1))  # (B, S, Hk, D)
     got = np.moveaxis(np.asarray(entry[n]), 1, 2)  # back to (B, S, Hk, D)
     np.testing.assert_array_equal(got, want, err_msg=n)
 print("OK")

@@ -367,7 +367,7 @@ def test_slot_rows_equal_paged_pool_rows(fmt):
         assert np.all(table[b, s // ps] >= 0)
         for n in slot:
             got, want = np.asarray(slot[n]), np.asarray(pool[n])[:, rows]
-            if kvc.token_major(slot, n):  # (L, B, S, Hk, D)
+            if kvc.token_major(slot, n):  # (L, B, S, Hk*D)
                 got = got[:, b, :pos[b]]
             else:  # scales (L, B, Hk, S) against pool rows (L, n, Hk)
                 got = np.swapaxes(got[:, b, :, :pos[b]], 1, 2)

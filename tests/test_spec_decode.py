@@ -210,7 +210,7 @@ class TestZeroTokenRange:
                                    max_seq=layout.max_seq)
         for n, a in out.items():
             arr = np.asarray(a)
-            # slot stacks: token-major bodies (L, B, S, Hk, D), heads-major
+            # slot stacks: token-row bodies (L, B, S, Hk*D), heads-major
             # scales and bgpp leaves (L, B, Hk, S, ...), k_planes carrying
             # an extra leading NBITS dim -> batch at bdim, tokens after it
             bdim = 2 if n == "k_planes" else 1

@@ -3,13 +3,15 @@ chip needed.
 
 Each kernel test lowers one Pallas kernel at phi4-mini-3.8b's published
 attention widths (8 kv heads, GQA group 3, head_dim 128) and the pool sizes
-``chip_smoke.py`` serves (8 slots, max_seq 1024) and compiles it for a
-described ``v5e:2x2`` with the TPU compiler installed here.  That is what
+``chip_smoke.py`` serves (8 slots, max_seq 1024) — the paged flash kernel
+also at the benchmark cells' pools — and compiles it for a described
+``v5e:2x2`` with the TPU compiler installed here.  That is what
 interpret mode cannot show: Mosaic refuses block shapes off the (8, 128)
 tiling and kernels that overrun the scoped VMEM.  The step tests compile the
 int8 slot-layout decode step and a prefill chunk at the same widths (a few
 layers) and read what the compiler made of the cache: no relayout of a whole
-K/V stack, and a decode step that updates its donated cache in place.
+K/V stack, no copy of a K/V layer or of a scale stack in the decode step,
+and a decode step that updates its donated cache in place.
 Nothing runs.
 """
 
@@ -26,7 +28,9 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels.bgpp_paged_attend.kernel import bgpp_paged_attend_pallas
-from repro.kernels.paged_flash_decode.kernel import paged_flash_decode_pallas
+from repro.kernels.paged_flash_decode.kernel import (
+    paged_flash_decode_pallas, slot_page_rows, slot_table,
+)
 from repro.models import model_zoo
 from repro.serving import engine, kv_cache as kvc
 
@@ -69,8 +73,9 @@ def _cfg():
 
 
 def _pool(layout_kind, fmt, page_size):
-    """(n_tok, page_size, pages_per_slot) of the pool the kernel sees: the
-    paged layout's own pool, or the slot stack viewed as one page per slot."""
+    """(n_tok, page_size, pages_per_slot) of the pool the bgpp kernel sees:
+    the paged layout's own pool, or the slot stack viewed as one page per
+    slot."""
     if layout_kind == "slot":
         return SLOTS * MAX_SEQ, MAX_SEQ, 1
     lay = kvc.layout_for(_cfg(), SLOTS, MAX_SEQ, kv_format=fmt,
@@ -84,27 +89,60 @@ def _compile(f, *args):
     return compiled
 
 
+# (config, slots, max_seq, model-axis shards): chip_smoke.py's phi4 pool,
+# the phi4-chat-int8 cell's, and one device's shard of the
+# deepseek7b-longdoc-1x4 cell's (8 of 32 kv heads)
+FLASH_SHAPES = [("phi4-mini-3.8b", SLOTS, MAX_SEQ, 1),
+                ("phi4-mini-3.8b", 20, 2048, 1),
+                ("deepseek-7b", 32, 4096, 4)]
+
+
 @pytest.mark.parametrize("fmt", ["bf16", "int8"])
 @pytest.mark.parametrize("layout_kind,page_size",
                          [("slot", None), ("paged", 8)])
-def test_paged_flash_decode_compiles(one_chip, fmt, layout_kind, page_size):
-    cfg = _cfg()
-    Hk, D = cfg.num_kv_heads, cfg.head_dim
-    g = cfg.num_heads // Hk
-    n_tok, P, pp = _pool(layout_kind, fmt, page_size)
+@pytest.mark.parametrize("name,slots,max_seq,shards", FLASH_SHAPES)
+def test_paged_flash_decode_compiles(one_chip, fmt, layout_kind, page_size,
+                                     name, slots, max_seq, shards):
+    """The kernel takes a two-layer stack and attends its second layer;
+    a slot stack is merged to ``(L, B*S, Hk*D)`` and read in sub-pages,
+    with its int8 scales read in place as ``(L, B, Hk, S)`` rows.  No op
+    outside the kernel slices, copies or relays one layer of K/V (at small
+    pools XLA may still prefetch a whole pool into VMEM: those moves are
+    ``slice-start``/``-done`` pieces, not a relayout)."""
+    cfg = get_config(name)
+    Hk, D = cfg.num_kv_heads // shards, cfg.head_dim
+    g = cfg.num_heads // cfg.num_kv_heads
     dt = jnp.bfloat16 if fmt == "bf16" else jnp.int8
-    args = [one_chip((SLOTS, Hk, g, D), jnp.float32),
-            one_chip((n_tok, Hk, D), dt), one_chip((n_tok, Hk, D), dt),
-            one_chip((SLOTS, pp), jnp.int32), one_chip((SLOTS,), jnp.int32)]
+    L = 2
+    if layout_kind == "slot":
+        P = slot_page_rows(max_seq, Hk * D, jnp.dtype(dt).itemsize)
+        kv = (L, slots, max_seq, Hk * D)
+        scale = (L, slots, Hk, max_seq)
+        table = lambda: slot_table(slots, max_seq, P)
+    else:
+        lay = kvc.layout_for(cfg, slots, max_seq, kv_format=fmt,
+                             layout="paged", page_size=page_size)
+        P, n_tok = page_size, lay.num_pages * page_size
+        kv = (L, n_tok, Hk * D)
+        scale = (L, n_tok, Hk)
+        table = lambda: jnp.zeros((slots, lay.pages_per_slot), jnp.int32)
+    args = [one_chip((slots, Hk, g, D), jnp.float32),
+            one_chip(kv, dt), one_chip(kv, dt), one_chip((slots,), jnp.int32)]
     if fmt == "int8":
-        args += [one_chip((n_tok, Hk), jnp.float32)] * 2
+        args += [one_chip(scale, jnp.float32)] * 2
 
-    def f(q, k, v, table, pos, *scales):
+    def f(q, k, v, pos, *scales):
         ks, vs = scales or (None, None)
-        return paged_flash_decode_pallas(q, k, v, table, pos, page_size=P,
-                                         k_scale=ks, v_scale=vs)
+        k = k.reshape(L, -1, Hk * D)
+        v = v.reshape(L, -1, Hk * D)
+        return paged_flash_decode_pallas(q, k, v, table(), pos, 1,
+                                         page_size=P, k_scale=ks, v_scale=vs)
 
-    _compile(f, *args)
+    compiled = _compile(f, *args)
+    layer = math.prod(kv) // L
+    made = [c for c in _entry_ops(compiled, r"(?:copy|slice|fusion|transpose)")
+            if c[1] >= layer]
+    assert not made, f"the kernel's operands are copied: {made}"
 
 
 @pytest.mark.parametrize("layout_kind,page_size",
@@ -167,16 +205,25 @@ def step_programs(one_chip):
     return {"serve_step": step, "prefill_chunk": chunk}, cache
 
 
-def _entry_ops(compiled, opcode=r"[\w-]+"):
-    """``(dtype, element count)`` of every array the entry computation's
-    ``opcode`` ops make (``copy`` is a relayout; async memory-space moves
-    are ``copy-start``/``-done``)."""
+# every opcode that makes an array, not a view of one
+_MAKES = r"(?!bitcast\(|parameter\()[\w-]+"
+
+
+def _entry_shapes(compiled, opcode=r"[\w-]+"):
+    """``(dtype, dims)`` of every array the entry computation's ``opcode``
+    ops make (``copy`` is a relayout; async memory-space moves are
+    ``copy-start``/``-done``)."""
     text = compiled.as_text()
     entry = text[text.index("\nENTRY"):]
     entry = entry[:entry.index("\n}")]
-    return [(dt, math.prod(int(d) for d in dims.split(",") if d))
+    return [(dt, tuple(int(d) for d in dims.split(",") if d))
             for dt, dims in re.findall(
                 rf"= (\w+)\[([\d,]*)\]\{{[^}}]*\}} {opcode}\(", entry)]
+
+
+def _entry_ops(compiled, opcode=r"[\w-]+"):
+    """``(dtype, element count)`` of :func:`_entry_shapes`."""
+    return [(dt, math.prod(dims)) for dt, dims in _entry_shapes(compiled, opcode)]
 
 
 @pytest.mark.parametrize("program", ["serve_step", "prefill_chunk"])
@@ -200,6 +247,26 @@ def test_prefill_chunk_reads_one_slot(step_programs):
     whole = [c for c in _entry_ops(progs["prefill_chunk"])
              if c[0] == "s8" and c[1] in layer]
     assert not whole, f"prefill_chunk makes whole K/V layers: {whole}"
+
+
+def test_serve_step_reads_kv_in_place(step_programs):
+    """The decode kernel reads the stacked cache in place: no op of the
+    step makes one layer of K/V (a slice for the custom call's operand, or
+    its ``(B*S, Hk*D)`` relayout), no ``(B*S, Hk)`` scale pool, and no
+    relayout of a whole scale stack around a layer's write."""
+    progs, cache = step_programs
+    glob = cache["global"]
+    layer = {glob[n].size // STEP_LAYERS for n in ("k", "v")}
+    shapes = _entry_shapes(progs["serve_step"], _MAKES)
+    slices = [c for c in shapes if c[0] == "s8" and math.prod(c[1]) in layer]
+    assert not slices, f"serve_step makes whole K/V layers: {slices}"
+    pool = (SLOTS * MAX_SEQ, _cfg().num_kv_heads)
+    pools = [c for c in shapes if c[0] == "f32" and c[1] == pool]
+    assert not pools, f"serve_step makes (B*S, Hk) scale pools: {pools}"
+    stack = tuple(glob["k_scale"].shape)
+    relaid = [c for c in _entry_shapes(progs["serve_step"], "copy")
+              if c == ("f32", stack)]
+    assert not relaid, f"serve_step relays whole scale stacks: {relaid}"
 
 
 def test_serve_step_donates_cache(step_programs):
